@@ -24,6 +24,21 @@ _ROW_FIELDS = ("name", "it_per_s", "us_per_call", "derived",
                "plan", "device_count", "mesh_shape")
 
 
+def require_cpu_for_child(devices: int) -> None:
+    """Guard before a mesh benchmark re-execs itself with virtual devices.
+
+    Only the CPU backend can grow devices in a child process.  On an
+    accelerator this process already holds the chips (asking for the device
+    count initialized the backend), and a child that needs them would fail
+    or hang, so refuse with the count that was missing."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"this benchmark needs {devices} devices but only "
+            f"{jax.device_count()} {jax.default_backend()} device(s) are "
+            "visible; it runs in this process and cannot hand the chips to "
+            "a child process")
+
+
 def time_iterations(step_fn: Callable, state, n_iter: int, warmup: int = 3,
                     windows: int = 3) -> Tuple[float, object]:
     """Returns (iterations/sec, final_state) for a jitted step.

@@ -33,7 +33,7 @@ import repro
 from repro.core.policies import make_transformer_policy
 from repro.core.rollout import forward_rollout
 
-from .common import row, time_iterations
+from .common import require_cpu_for_child, row, time_iterations
 
 KEY = jax.random.PRNGKey(0)
 
@@ -142,7 +142,7 @@ def _mesh_rows(quick: bool, shards: int):
       1 vs ``shards`` devices): meaningful on real multi-chip hardware;
       recorded for the trajectory, oversubscription-bound on small CPUs.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.policies import make_mlp_policy
@@ -175,7 +175,7 @@ def _mesh_rows(quick: bool, shards: int):
             return b.log_reward
 
         sharded = shard_map(local, mesh=mesh, in_specs=(P(),),
-                            out_specs=P("batch"), check_rep=False)
+                            out_specs=P("batch"), check_vma=False)
 
         @jax.jit
         def step(key):
@@ -213,11 +213,14 @@ def _mesh_rows(quick: bool, shards: int):
 
 def run_mesh(quick: bool = True, shards: int = MESH_SHARDS):
     """Entry point for the ``mesh`` benchmark suite: runs in-process when
-    enough devices are visible, otherwise re-execs itself in a subprocess
-    with ``--xla_force_host_platform_device_count`` (the backend's device
-    count is fixed at first use, so a 1-device parent can't grow one)."""
+    enough devices are visible.  On the CPU it otherwise re-execs itself in
+    a subprocess with ``--xla_force_host_platform_device_count`` (the
+    backend's device count is fixed at first use, so a 1-device parent
+    can't grow one); on an accelerator this process already holds the
+    chips, so a child could not take them and the call fails instead."""
     if jax.device_count() >= shards:
         return _mesh_rows(quick, shards)
+    require_cpu_for_child(shards)
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]

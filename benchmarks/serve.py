@@ -51,7 +51,7 @@ import time
 import jax
 import numpy as np
 
-from .common import row
+from .common import require_cpu_for_child, row
 
 SERVE_MESH_SHARDS = 4
 
@@ -358,11 +358,11 @@ def _mesh_serve_rows(quick: bool, shards: int):
 
 def run_mesh_serve(quick: bool = True, shards: int = SERVE_MESH_SHARDS):
     """Multi-device serve rows: in-process when enough devices are visible,
-    else re-exec'd with ``--xla_force_host_platform_device_count`` (the
-    backend's device count is fixed at first use, so a 1-device parent
-    can't grow one — the same trick ``benchmarks.rollout.run_mesh`` uses)."""
+    else (CPU only) re-exec'd with ``--xla_force_host_platform_device_count``
+    — the same rule as ``benchmarks.rollout.run_mesh``."""
     if jax.device_count() >= shards:
         return _mesh_serve_rows(quick, shards)
+    require_cpu_for_child(shards)
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]

@@ -40,7 +40,7 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.types import replace
@@ -253,7 +253,7 @@ class DataParallelPlan(ExecutionPlan):
             local_fn, mesh=mesh,
             in_specs=(P(), samp_spec),
             out_specs=((P(), samp_spec), (P(), batch_specs)),
-            check_rep=False)
+            check_vma=False)
 
         def step_fn(state):
             (train, sampler), out = sharded(state.train, state.sampler)

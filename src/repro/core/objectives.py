@@ -92,13 +92,12 @@ def evaluate_trajectory(policy_apply: PolicyApply, params,
     def unflat(x):
         return x.reshape((Tp1, B) + x.shape[1:])
 
-    # On TPU with compiled kernels the mask + log-softmax + action gather
-    # fuses into one Pallas pass per direction (kernels.ops.traj_logprob,
-    # closed-form VJP); stop-probability extraction needs the full
-    # log-softmax tensor, so envs with a stop head keep the jnp path.
-    from ..kernels.ops import pallas_compiled, traj_logprob
-    fused = (stop_action is None and jax.default_backend() == "tpu"
-             and pallas_compiled())
+    # On the TPU the mask + log-softmax + action gather fuses into one
+    # Pallas pass per direction (kernels.ops.traj_logprob, closed-form VJP);
+    # stop-probability extraction needs the full log-softmax tensor, so envs
+    # with a stop head keep the jnp path.
+    from ..kernels.ops import traj_logprob
+    fused = stop_action is None and jax.default_backend() == "tpu"
 
     logits = unflat(out["logits"])
     if fused:
@@ -288,14 +287,12 @@ def subtb_loss(ev: TrajEval, batch: RolloutBatch, lam: float = 0.9,
         reassociation; see ``tests/test_objectives.py``);
       - "pallas": the tiled Pallas kernel (``kernels/subtb_loss.py``)
         forward, prefix-recurrence backward (``jax.grad``-safe);
-      - "auto":   pallas on TPU with compiled lowering enabled
-        (``REPRO_PALLAS_COMPILE=1``), else dense for small T and prefix
+      - "auto":   pallas on the TPU, else dense for small T and prefix
         beyond ``_SUBTB_DENSE_MAX_T1`` states.
     """
-    from ..kernels.ops import pallas_compiled
     phi, length = _subtb_phi(ev, batch)
     if impl == "auto":
-        if jax.default_backend() == "tpu" and pallas_compiled():
+        if jax.default_backend() == "tpu":
             impl = "pallas"
         else:
             impl = "dense" if phi.shape[0] <= _SUBTB_DENSE_MAX_T1 \

@@ -45,9 +45,9 @@ class Policy(NamedTuple):
     categorical sampling issued as one op from the rollout scan body / serve
     lane step.  On CPU it composes the exact same jnp ops as the unfused
     ``apply_cached`` + ``sample_masked_per_env`` chain (bitwise-identical
-    trajectories); on TPU with ``REPRO_PALLAS_COMPILE=1`` and statically-
-    zero ``eps`` it lowers the whole step through the fused Pallas kernel
-    (``kernels.ops.decode_step``).
+    trajectories); on the TPU with statically-zero ``eps`` it lowers the
+    whole step through the fused Pallas kernel (``kernels.ops.decode_step``).
+    The choice is by platform and input only.
 
     Continuous-action policies (``nn.flows``, for envs with
     ``continuous_actions = True``) leave the categorical surface unused and
@@ -236,11 +236,8 @@ def make_transformer_policy(vocab_size: int, max_len: int, action_dim: int,
         Returns ``(actions, log_pf, out, cache)`` with ``out`` the full
         heads dict (same as ``apply_cached``'s).
         """
-        from ..kernels.ops import pallas_compiled
         eps_zero = isinstance(eps, (int, float)) and eps == 0.0
-        use_kernel = (eps_zero and jax.default_backend() == "tpu"
-                      and pallas_compiled())
-        if use_kernel:
+        if eps_zero and jax.default_backend() == "tpu":
             from ..kernels.ops import decode_step
             x_new = _embed(params, token.astype(jnp.int32), pos)
             slot = jnp.max(length) if step is None else step

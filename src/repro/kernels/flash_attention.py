@@ -11,8 +11,8 @@ grid = (B, H, n_q_blocks, n_kv_blocks); GQA is expressed in the k/v
 BlockSpec index maps (q head h reads kv head h // group_size), so no
 repeated-KV materialization ever happens.
 
-Validated on CPU in interpret mode against kernels.ref.ref_flash_attention
-(the real-hardware path is identical modulo `interpret=`).
+Validated in interpret mode against kernels.ref.ref_flash_attention
+(``interpret=None`` lowers through Mosaic on the TPU, interprets elsewhere).
 """
 from __future__ import annotations
 
@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -76,12 +78,12 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            kv_len: Optional[int] = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, H, D); k/v: (B, Skv, KVH, D).  Returns (B, Sq, H, D).
 
     Sq/Skv are padded to block multiples internally; GQA handled via the
-    kv index map.  ``interpret=True`` executes on CPU for validation; on a
-    real TPU pass ``interpret=False``.
+    kv index map.  ``interpret=None`` picks Mosaic on the TPU and the
+    interpreter elsewhere.
     """
     B, Sq, H, D = q.shape
     Skv, KVH = k.shape[1], k.shape[2]
@@ -129,7 +131,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom l
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
 
     out = jnp.swapaxes(out, 1, 2)

@@ -1,13 +1,12 @@
 """Jitted public wrappers for the Pallas kernels (the ``ops.py`` contract).
 
-``interpret`` defaults to True because this container is CPU-only; on real
-TPU hardware pass ``interpret=False`` (or set REPRO_PALLAS_COMPILE=1) and
-the identical kernels lower through Mosaic.
+Every wrapper lowers its kernel through Mosaic when the default backend is
+the TPU and runs the same kernel in the Pallas interpreter on any other
+platform (:func:`repro.kernels.resolve_interpret`); there is no switch.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -20,16 +19,6 @@ from .rwkv6_scan import rwkv6_scan_pallas
 from .subtb_loss import subtb_loss_pallas
 from .traj_logprob import traj_logprob_pallas
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
-
-def pallas_compiled() -> bool:
-    """True when the kernels lower through Mosaic (REPRO_PALLAS_COMPILE=1)
-    rather than the interpreter — hot-path callers should only prefer a
-    kernel over their jnp fallback when this holds."""
-    return not _INTERPRET
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "kv_len",
                                              "block_q", "block_k"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -39,7 +28,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """GQA flash attention.  q: (B, Sq, H, D); k/v: (B, Skv, KVH, D)."""
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   kv_len=kv_len, block_q=block_q,
-                                  block_k=block_k, interpret=_INTERPRET)
+                                  block_k=block_k)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k",))
@@ -48,8 +37,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Single-query decode attention against a KV cache.
 
     q: (B, H, D); k/v: (B, S, H, D); kv_valid: (B,) valid slot counts."""
-    return decode_attention_pallas(q, k, v, kv_valid, block_k=block_k,
-                                   interpret=_INTERPRET)
+    return decode_attention_pallas(q, k, v, kv_valid, block_k=block_k)
 
 
 def decode_attention_grad(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -85,7 +73,7 @@ def decode_step(w, x_new: jax.Array, cache, lengths: jax.Array,
                 w_out: jax.Array, b_out: jax.Array,
                 logit_temp: Optional[jax.Array] = None, *, num_heads: int):
     """Fused cached-rollout step: cache append + latent-query decode +
-    masked Gumbel-max sampling in one Pallas program per environment.
+    masked Gumbel-max sampling in one Pallas program per 8 environments.
 
     ``cache`` is the transformer-layout stacked pair ``{"k", "v"}`` of
     (num_layers, B, C, H, hd) arrays; this wrapper merges the head axes for
@@ -100,7 +88,7 @@ def decode_step(w, x_new: jax.Array, cache, lengths: jax.Array,
     action, log_pf, y, new_k, new_v = decode_step_pallas(
         w, x_new, cache["k"].reshape(L, B, C, D),
         cache["v"].reshape(L, B, C, D), lengths, slot, gumbel, action_mask,
-        w_out, b_out, logit_temp, num_heads=num_heads, interpret=_INTERPRET)
+        w_out, b_out, logit_temp, num_heads=num_heads)
     return action, log_pf, y, {"k": new_k.reshape(L, B, C, H, hd),
                                "v": new_v.reshape(L, B, C, H, hd)}
 
@@ -119,7 +107,7 @@ def traj_logprob(logits: jax.Array, actions: jax.Array, mask: jax.Array,
     @jax.custom_vjp
     def f(lg):
         return traj_logprob_pallas(lg, actions, mask, valid,
-                                   block_t=block_t, interpret=_INTERPRET)
+                                   block_t=block_t)
 
     def fwd(lg):
         return f(lg), lg
@@ -143,13 +131,11 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                u: Optional[jax.Array] = None, chunk: int = 64
                ) -> Tuple[jax.Array, jax.Array]:
     """RWKV6 wkv recurrence; returns (out, final_state)."""
-    return rwkv6_scan_pallas(r, k, v, w, u, chunk=chunk,
-                             interpret=_INTERPRET)
+    return rwkv6_scan_pallas(r, k, v, w, u, chunk=chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "block"))
 def subtb_loss(phi: jax.Array, length: jax.Array, lam: float = 0.9,
                block: int = 128) -> jax.Array:
     """Per-trajectory SubTB(lambda) losses from potentials phi (B, T+1)."""
-    return subtb_loss_pallas(phi, length, lam=lam, block=block,
-                             interpret=_INTERPRET)
+    return subtb_loss_pallas(phi, length, lam=lam, block=block)

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
                  s_scr, *, chunk: int, n_chunks: int, use_bonus: bool):
@@ -72,7 +74,7 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref,
 
 def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array,
                       w: jax.Array, u: Optional[jax.Array] = None,
-                      chunk: int = 64, interpret: bool = True
+                      chunk: int = 64, interpret: Optional[bool] = None
                       ) -> Tuple[jax.Array, jax.Array]:
     """r/k/w: (B, T, H, Dk); v: (B, T, H, Dv); u: (H, Dk) or None.
     Returns (o: (B, T, H, Dv), state: (B, H, Dk, Dv)).  T padded to chunk."""
@@ -115,7 +117,7 @@ def rwkv6_scan_pallas(r: jax.Array, k: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((B, H, Dk, Dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rt, kt, vt, wt, u)
 
     o = jnp.swapaxes(o, 1, 2)[:, :T]

@@ -3,6 +3,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run (assignment: MULTI-POD DRY-RUN steps 0-4).
 
+A CPU-only tool: importing this module forces 512 virtual CPU devices
+through ``XLA_FLAGS`` (above), so no path that runs on a chip — the
+training and serving entry points, ``chip_smoke.py`` — ever imports it.
+
 Lowers + compiles train_step / serve_step / prefill for every
 (architecture x input shape) on the single-pod 16x16 mesh and the 2x16x16
 multi-pod mesh, records memory_analysis() + cost_analysis() + collective
@@ -182,13 +186,9 @@ def lower_cell(arch: str, shape_id: str, mesh, *, smoke: bool = False,
 
 
 def _cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: older
-    releases return a per-device *list* of dicts, newer ones a single dict
-    (and either may be None when the backend records no cost metadata)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict (None when the backend
+    records no cost metadata)."""
+    return compiled.cost_analysis() or {}
 
 
 def run_cell(arch: str, shape_id: str, mesh_kind: str, *,

@@ -30,11 +30,14 @@ def make_mesh(shape, axes):
     """Generic mesh for plans/tests/benchmarks (e.g. ``((4,), ("batch",))``
     on an 8-virtual-device CPU).  Uses ``jax.make_mesh`` when the shape
     consumes every visible device (it reorders devices for locality) and
-    falls back to the first ``prod(shape)`` devices otherwise."""
+    falls back to the first ``prod(shape)`` devices otherwise.  Either way
+    the axes are ``Auto``: the plans place data with ``shard_map`` and
+    ``NamedSharding``, not with sharding-in-types."""
     shape = tuple(shape)
     n = math.prod(shape)
     if n == jax.device_count():
-        return jax.make_mesh(shape, tuple(axes))
+        return jax.make_mesh(shape, tuple(axes), axis_types=(
+            jax.sharding.AxisType.Auto,) * len(shape))
     if n > jax.device_count():
         raise ValueError(
             f"mesh shape {shape} needs {n} devices but only "

@@ -244,18 +244,15 @@ def encoder_query_cached(p: Params, cache: Params, lengths: jax.Array, *,
     (BOS + the env's tokens).  Returns (B, D).
 
     ``attn_impl``: "jnp" (masked softmax, the CPU path), "kernel" (the
-    Pallas decode-attention kernel), or "auto" (kernel only when on TPU
-    *and* the kernels lower through Mosaic — ``REPRO_PALLAS_COMPILE=1``;
-    an interpret-mode kernel on the rollout hot path would be far slower
-    than the jnp fallback).
+    Pallas decode-attention kernel), or "auto" (the kernel on the TPU, where
+    it lowers through Mosaic; jnp elsewhere, since an interpret-mode kernel
+    on the rollout hot path would be far slower than the jnp path).
     """
     ks = cache["k"]
     B, C = ks.shape[1], ks.shape[2]
     dim = ks.shape[3] * ks.shape[4]
     if attn_impl == "auto":
-        from ..kernels.ops import pallas_compiled
-        attn_impl = "kernel" if (jax.default_backend() == "tpu"
-                                 and pallas_compiled()) else "jnp"
+        attn_impl = "kernel" if jax.default_backend() == "tpu" else "jnp"
     if attn_impl == "kernel":
         from ..kernels.ops import decode_attention
         kv_valid = lengths.astype(jnp.int32) + 1          # + BOS slot
@@ -295,11 +292,10 @@ def encoder_step_cached(p: Params, x_new: jax.Array, cache: Params,
     or a (B,) vector (serve lanes).  Returns ``(y (B, D), new_cache)``.
 
     On the jnp path this is exactly ``cache_append`` + ``encoder_query_cached``
-    (bitwise parity with the unfused chain); when the Pallas kernels compile
-    (TPU + ``REPRO_PALLAS_COMPILE=1``) the attention itself lowers through
-    the decode-attention kernel, and the fully-fused sampling variant lives
-    one level up in ``core.policies`` (which also folds in masked sampling
-    via ``kernels.ops.decode_step``).
+    (bitwise parity with the unfused chain); on the TPU the attention itself
+    lowers through the decode-attention kernel, and the fully-fused sampling
+    variant lives one level up in ``core.policies`` (which also folds in
+    masked sampling via ``kernels.ops.decode_step``).
     """
     cache = cache_append(p, cache, x_new, slot, num_heads=num_heads)
     y = encoder_query_cached(p, cache, lengths, num_heads=num_heads,

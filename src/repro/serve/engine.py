@@ -96,7 +96,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.rollout import _cache_engaged, _policy_entry
@@ -361,14 +361,14 @@ class SamplingEngine:
                 # per-shard partial counts -> one replicated global scalar
                 return lane, nd, jax.lax.psum(cnt, axis)
 
-            # check_rep=False: every op is row-local; there is nothing
+            # check_vma=False: every op is row-local; there is nothing
             # replicated to verify and the check defeats prefix specs
             self._jstep = jax.jit(shard_map(
                 block_psum, mesh=mesh, in_specs=(specs,),
-                out_specs=(specs, lane_sp, P()), check_rep=False))
+                out_specs=(specs, lane_sp, P()), check_vma=False))
             self._jrefill = jax.jit(shard_map(
                 refill, mesh=mesh, in_specs=(specs,) + (lane_sp,) * 6,
-                out_specs=specs, check_rep=False))
+                out_specs=specs, check_vma=False))
         else:
             self._jstep = jax.jit(block)
             self._jrefill = jax.jit(refill)

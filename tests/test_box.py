@@ -276,7 +276,7 @@ class TestPlanParity:
                "unless XLA_FLAGS was preset")
 
     def test_sharded_forward_rollout_bitwise_identical(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from repro.distributed.sharding import rollout_batch_specs
         from repro.launch.mesh import make_mesh
@@ -295,7 +295,7 @@ class TestPlanParity:
 
         shb = jax.jit(shard_map(local, mesh=mesh, in_specs=(),
                                 out_specs=rollout_batch_specs("batch"),
-                                check_rep=False))()
+                                check_vma=False))()
         np.testing.assert_array_equal(np.asarray(full.actions),
                                       np.asarray(shb.actions))
         np.testing.assert_array_equal(np.asarray(full.done),

@@ -165,7 +165,7 @@ class TestCompression:
     def test_compressed_psum_on_mesh(self):
         """shard_map int8 psum matches exact psum within quantization tol."""
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = jax.make_mesh((1,), ("pod",))
         x = jax.random.normal(KEY, (8, 16))
 

@@ -56,7 +56,7 @@ def _parity(env, env_params, policy, cfg, n=25, rtol=2e-3):
 
 class TestRolloutParity:
     def test_sharded_forward_rollout_samples_identical_actions(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from repro.distributed.sharding import rollout_batch_specs
         from repro.launch.mesh import make_mesh
@@ -79,7 +79,7 @@ class TestRolloutParity:
 
         shb = jax.jit(shard_map(local, mesh=mesh, in_specs=(),
                                 out_specs=rollout_batch_specs("batch"),
-                                check_rep=False))()
+                                check_vma=False))()
         np.testing.assert_array_equal(np.asarray(full.actions),
                                       np.asarray(shb.actions))
         np.testing.assert_array_equal(np.asarray(full.done),
@@ -198,7 +198,7 @@ class TestPerShardFIFO:
     def test_shards_stay_disjoint_under_shard_map(self, capacity, batch):
         """Each shard's buffer only ever holds items that shard inserted,
         and sampling returns only local items."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.mesh import make_mesh
@@ -221,7 +221,7 @@ class TestPerShardFIFO:
 
         run = jax.jit(shard_map(local, mesh=mesh, in_specs=(P("batch"),),
                                 out_specs=(P("batch"), P("batch")),
-                                check_rep=False))
+                                check_vma=False))
         state, sampled = run(state0)
         data = np.asarray(state.data["x"])       # (SHARDS, capacity/SHARDS)
         sampled = np.asarray(sampled)            # (SHARDS, 32)

@@ -1,0 +1,149 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e, off the chip.
+
+Interpret mode accepts block shapes, scalar layouts and lane slices that
+Mosaic refuses, so every kernel the training and serving paths lower on the
+TPU is compiled here with ``interpret=False`` for one chip of a described
+``v5e:2x2`` topology, at the shapes ``chip_smoke.py`` drives: the
+``bitseq_tb`` recipe (n=120, k=8: 15 words, 3840 forward actions, a 3-layer
+width-64 8-head decode transformer) at the paper's 16 envs and at 256, and
+the ``hypergrid_subtb`` paper grid (20^4: 77-step trajectories).  Nothing
+runs; a compile that passes says the compiler accepts the kernel, not that
+its results are right (the interpret-mode oracle tests cover that).
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and test collection must be the
+same on every pytest-xdist worker.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.decode_attention import (decode_attention_pallas,
+                                            decode_step_pallas)
+from repro.kernels.subtb_loss import subtb_loss_pallas
+from repro.kernels.traj_logprob import traj_logprob_pallas
+
+# bitseq_tb recipe defaults (recipes/seqs.py) and the hypergrid 20^4 grid
+LAYERS, DIM, HEADS, FF = 3, 64, 8, 256
+WORDS, ACTIONS = 15, 15 * 256          # L = n / k positions, L * 2^k actions
+CAPACITY = WORDS + 1                   # cache slots: BOS + one per word
+SUBTB_STATES = 4 * 19 + 2              # T + 1 for dim=4, side=20
+BATCHES = (16, 256)                    # paper num_envs, and a chip-filling one
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """``spec(shape, dtype)``: an abstract argument on one described chip."""
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A described-topology compile is written to the persistent cache but
+    cannot be read back without a chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernel_calls(fn, *args) -> int:
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def _assert_kernel(fn, *args):
+    assert _kernel_calls(fn, *args) >= 1
+
+
+def _decoder_weights(spec):
+    L, D, F = LAYERS, DIM, FF
+    shapes = {"ln1_scale": (L, D), "ln1_bias": (L, D), "q_w": (L, D, D),
+              "q_b": (L, D), "kv_w": (L, D, 2 * D), "kv_b": (L, 2 * D),
+              "proj_w": (L, D, D), "proj_b": (L, D), "ln2_scale": (L, D),
+              "ln2_bias": (L, D), "ff1_w": (L, D, F), "ff1_b": (L, F),
+              "ff2_w": (L, F, D), "ff2_b": (L, D), "ln_f_scale": (D,),
+              "ln_f_bias": (D,), "q0": (D,)}
+    return {k: spec(s) for k, s in shapes.items()}
+
+
+@pytest.mark.parametrize("batch", BATCHES)
+def test_decode_step_compiles(spec, batch):
+    B, L, C, D, A = batch, LAYERS, CAPACITY, DIM, ACTIONS
+    step = lambda w, x, kc, vc, n, s, g, m, wo, bo, t: decode_step_pallas(
+        w, x, kc, vc, n, s, g, m, wo, bo, t, num_heads=HEADS,
+        interpret=False)
+    _assert_kernel(step, _decoder_weights(spec), spec((B, D)),
+                   spec((L, B, C, D)), spec((L, B, C, D)),
+                   spec((B,), jnp.int32), spec((B,), jnp.int32),
+                   spec((B, A)), spec((B, A), jnp.bool_), spec((D, A)),
+                   spec((A,)), spec((B,)))
+
+
+@pytest.mark.parametrize("batch", BATCHES)
+def test_decode_attention_compiles(spec, batch):
+    hd = DIM // HEADS
+    _assert_kernel(
+        lambda q, k, v, n: decode_attention_pallas(q, k, v, n,
+                                                   interpret=False),
+        spec((batch, HEADS, hd)), spec((batch, CAPACITY, HEADS, hd)),
+        spec((batch, CAPACITY, HEADS, hd)), spec((batch,), jnp.int32))
+
+
+@pytest.mark.parametrize("batch", BATCHES)
+@pytest.mark.parametrize("actions", [ACTIONS, WORDS], ids=["fwd", "bwd"])
+def test_traj_logprob_compiles(spec, batch, actions):
+    B, T = batch, WORDS
+    _assert_kernel(
+        lambda lg, a, m, v: traj_logprob_pallas(lg, a, m, v,
+                                                interpret=False),
+        spec((B, T, actions)), spec((B, T), jnp.int32),
+        spec((B, T, actions), jnp.bool_), spec((B, T), jnp.bool_))
+
+
+@pytest.mark.parametrize("batch", BATCHES)
+def test_subtb_loss_compiles(spec, batch):
+    _assert_kernel(
+        lambda phi, n: subtb_loss_pallas(phi, n, lam=0.9, interpret=False),
+        spec((batch, SUBTB_STATES)), spec((batch,), jnp.int32))
+
+
+def test_bitseq_tb_train_step_compiles(spec, monkeypatch):
+    """The whole jitted ``bitseq_tb`` step at the paper's 16 envs: the
+    platform gates see a TPU (steered here, since this process's backend is
+    the CPU), so the rollout's cached queries lower through
+    ``decode_attention`` (3 layers) and the TB objective through
+    ``traj_logprob`` (forward and backward log-probs)."""
+    from repro import recipes
+    from repro.algo import TrainLoop
+    from repro.recipes.base import RunOptions
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    recipe = recipes.get("bitseq_tb")
+    env = recipe.make_env()
+    policy = recipe.make_policy(env)
+    cfg = recipe.make_config(env, RunOptions(num_envs=BATCHES[0]))
+    loop = TrainLoop(env, env.init(jax.random.PRNGKey(0)), policy, cfg)
+    state = jax.tree_util.tree_map(
+        lambda s: spec(s.shape, s.dtype),
+        jax.eval_shape(loop.init, jax.random.PRNGKey(1)))
+    assert _kernel_calls(loop.step_fn, state) == LAYERS + 2
