@@ -1,0 +1,16 @@
+"""Time the engine's drain waits for the previous block's done-count
+(span ``serve.sync`` around its readback in ``serve/engine.py``); the
+median over the traced window, in milliseconds.  Read from the program's
+span records (``repro.serve.spans``); none where the program records no
+spans."""
+from bench import harness
+
+
+def read(run):
+    try:
+        from repro.serve import spans
+    except ImportError:
+        return None
+    d = [s.end_ns - s.start_ns for s in spans.snapshot()
+         if s.name == "serve.sync"]
+    return 1e-6 * harness.percentile(d, 50) if d else None

@@ -42,6 +42,16 @@ compaction (:math:`O(L)` argsort, done lanes first) packs the terminal
 rows so the host fetches exactly ``count`` rows of
 (obs, log_r, request_id, env_id, t) instead of five full-pool arrays.
 
+Spans and device calls
+----------------------
+While a profiler session is active the engine records, through
+:mod:`repro.serve.spans`, ``serve.sync`` (the count's readback),
+``serve.fetch`` (compaction and the ``rows`` fetched), ``serve.refill``
+(``filled`` lanes) and ``serve.dispatch`` (the block, with ``lanes_busy``
+of ``lanes``).  ``counters["device_calls"]`` counts every call of the
+engine that reaches the device, a program or a transfer, recorder on or
+off.
+
 Cross-request dedup
 -------------------
 With ``dedup_cache_size > 0`` (the :class:`repro.serve.Scheduler` default),
@@ -103,6 +113,7 @@ from ..core.rollout import _cache_engaged, _policy_entry
 from ..core.types import pytree_dataclass, sample_masked_per_env
 from ..envs.base import Environment, _select_state
 from ..envs.transforms import RewardExponent, TransformedParams
+from . import spans
 from .errors import EngineFailure, LanePoisoned
 
 # One process can host several sharded engines (the front runs one per
@@ -213,7 +224,6 @@ class SamplingEngine:
         self._next_id = 0
         self._occupied = np.zeros(L, bool)
         self._undrained = None      # newly_done of the in-flight block
-        self.steps_run = 0
         self._faults = fault_plan
         self.max_step_retries = int(max_step_retries)
         self.retry_backoff_s = float(retry_backoff_s)
@@ -222,12 +232,15 @@ class SamplingEngine:
         self._dedup_inflight: Dict[tuple, int] = {}     # ckey -> primary
         self._dedup_key_of: Dict[int, tuple] = {}       # primary -> ckey
         self._dedup_waiters: Dict[int, List[int]] = {}  # primary -> rids
-        #: robustness + perf counters surfaced through the front's /stats
+        #: robustness + perf counters surfaced through the front's /stats;
+        #: ``device_calls`` counts the engine's calls that reach the device
+        #: (:meth:`_dispatch`, :meth:`_fetch`, :meth:`_put`)
         self.counters: Dict[str, int] = {
             "requests": 0, "completed": 0, "cancelled": 0,
             "blocks": 0, "step_retries": 0, "step_failures": 0,
             "drain_skips": 0, "drain_packs": 0, "resizes": 0,
-            "dedup_hits": 0, "dedup_joins": 0, "dedup_misses": 0}
+            "dedup_hits": 0, "dedup_joins": 0, "dedup_misses": 0,
+            "device_calls": 0}
 
         env_w = self.env
 
@@ -421,13 +434,29 @@ class SamplingEngine:
         the deadlock this prevents).  The forced sync costs nothing in
         that regime: the virtual devices time-slice the same host, so
         there is no cross-program compute overlap to preserve.
+        Counts one device call.
         """
+        self.counters["device_calls"] += 1
         if self._shards == 1:
             return fn(*args)
         with _MESH_DISPATCH:
             out = fn(*args)
             jax.block_until_ready(out)
         return out
+
+    def _fetch(self, x, rows: Optional[int] = None) -> np.ndarray:
+        """Copy a device array, or its first ``rows`` rows, to the host:
+        one device call for the transfer and one for the slice."""
+        if rows is not None:
+            self.counters["device_calls"] += 1
+            x = x[:rows]
+        self.counters["device_calls"] += 1
+        return np.asarray(x)
+
+    def _put(self, x: np.ndarray) -> jax.Array:
+        """Copy a host array to the device: one device call."""
+        self.counters["device_calls"] += 1
+        return jnp.asarray(x)
 
     def resize(self, num_lanes: int) -> bool:
         """Rebuild the lane pool at a new size between requests.  Returns
@@ -490,9 +519,9 @@ class SamplingEngine:
         rid = self._next_id
         self._next_id += 1
         if key is None:
-            key = jax.random.PRNGKey(seed)
-        step_keys = np.asarray(jax.random.split(key, self.T),
-                               dtype=np.uint32)
+            key = self._dispatch(jax.random.PRNGKey, seed)
+        step_keys = self._fetch(
+            self._dispatch(jax.random.split, key, self.T)).astype(np.uint32)
         self.counters["requests"] += 1
         if self.dedup_cache_size:
             # everything request-scoped in the parity contract; the engine
@@ -531,31 +560,31 @@ class SamplingEngine:
         free = np.nonzero(~self._occupied)[0]
         if free.size == 0:
             return
-        L, T = self.num_lanes, self.T
-        mask = np.zeros(L, bool)
-        step_keys = np.zeros((L, T, 2), np.uint32)
-        env_id = np.zeros(L, np.int32)
-        request_id = np.zeros(L, np.int32)
-        logit_temp = np.ones(L, np.float32)
-        reward_beta = np.ones(L, np.float32)
-        for b in free:
-            if not self._pending:
-                break
-            s = self._pending.popleft()
-            mask[b] = True
-            step_keys[b] = s.step_keys
-            env_id[b] = s.env_id
-            request_id[b] = s.request_id
-            logit_temp[b] = s.logit_temp
-            reward_beta[b] = s.reward_beta
-            self._occupied[b] = True
-        self.lane = self._dispatch(self._jrefill, self.lane,
-                                   jnp.asarray(mask),
-                                   jnp.asarray(step_keys),
-                                   jnp.asarray(env_id),
-                                   jnp.asarray(request_id),
-                                   jnp.asarray(logit_temp),
-                                   jnp.asarray(reward_beta))
+        with spans.span("serve.refill") as sp:
+            L, T = self.num_lanes, self.T
+            mask = np.zeros(L, bool)
+            step_keys = np.zeros((L, T, 2), np.uint32)
+            env_id = np.zeros(L, np.int32)
+            request_id = np.zeros(L, np.int32)
+            logit_temp = np.ones(L, np.float32)
+            reward_beta = np.ones(L, np.float32)
+            for b in free:
+                if not self._pending:
+                    break
+                s = self._pending.popleft()
+                mask[b] = True
+                step_keys[b] = s.step_keys
+                env_id[b] = s.env_id
+                request_id[b] = s.request_id
+                logit_temp[b] = s.logit_temp
+                reward_beta[b] = s.reward_beta
+                self._occupied[b] = True
+            self.lane = self._dispatch(
+                self._jrefill, self.lane, *map(self._put, (
+                    mask, step_keys, env_id, request_id, logit_temp,
+                    reward_beta)))
+            if sp:
+                sp.set(filled=int(mask.sum()))
 
     def _drain_pending(self) -> int:
         """Drain the completions of the last dispatched block against the
@@ -571,20 +600,21 @@ class SamplingEngine:
         nd, cnt = und
         # the count was computed inside the block's own dispatch; reading
         # it back is the drain's entire cost when nothing finished
-        count = int(jax.device_get(cnt))
+        with spans.span("serve.sync"):
+            count = int(self._fetch(cnt))
         if count == 0:
             self.counters["drain_skips"] += 1
             return 0
         self.counters["drain_packs"] += 1
-        order, obs, log_r, rid, eid, steps = self._dispatch(
-            self._jpack, self.lane, nd)
-        k = count
-        order = np.asarray(order[:k])
-        obs = np.asarray(obs[:k])
-        log_r = np.asarray(log_r[:k])
-        rid = np.asarray(rid[:k])
-        eid = np.asarray(eid[:k])
-        steps = np.asarray(steps[:k])
+        with spans.span("serve.fetch", rows=count):
+            return self._collect(count, nd)
+
+    def _collect(self, k: int, nd) -> int:
+        """Fetch the ``k`` finished rows of the pool (compacted first by
+        ``_jpack``) and fold them into their requests."""
+        packed = self._dispatch(self._jpack, self.lane, nd)
+        order, obs, log_r, rid, eid, steps = (self._fetch(x, k)
+                                              for x in packed)
         rows = []
         for i in range(k):
             b, r = int(order[i]), int(rid[i])
@@ -688,8 +718,12 @@ class SamplingEngine:
                     if self._faults.fires("lane_state"):
                         self._poison_occupied_lanes()
                     self._faults.maybe_raise("engine_step")
-                lane, newly_done, cnt = self._dispatch(self._jstep,
-                                                       self.lane)
+                with spans.span("serve.dispatch",
+                                lanes=self.num_lanes) as sp:
+                    lane, newly_done, cnt = self._dispatch(self._jstep,
+                                                           self.lane)
+                    if sp:
+                        sp.set(lanes_busy=int(self._occupied.sum()))
                 break
             except Exception as e:
                 attempt += 1
@@ -703,7 +737,6 @@ class SamplingEngine:
         self.lane = lane
         self._undrained = (newly_done, cnt)
         self.counters["blocks"] += 1
-        self.steps_run += self.steps_per_sync
         return finished
 
     # -- robustness surface (used by repro.serve.front) -----------------------
@@ -781,7 +814,7 @@ class SamplingEngine:
                 self._pending = deque(
                     s._replace(request_id=new) if s.request_id == rid
                     else s for s in self._pending)
-            if ((np.asarray(self.lane.request_id) == rid)
+            if ((self._fetch(self.lane.request_id) == rid)
                     & self._occupied).any():
                 self.lane = self._dispatch(self._jreassign, self.lane,
                                            rid, new)
@@ -793,7 +826,7 @@ class SamplingEngine:
         self._pending = deque(s for s in self._pending
                               if s.request_id != rid)
         removed = before - len(self._pending)
-        mask = (np.asarray(self.lane.request_id) == rid) & self._occupied
+        mask = (self._fetch(self.lane.request_id) == rid) & self._occupied
         lanes_freed = int(mask.sum())
         if lanes_freed:
             L, T = self.num_lanes, self.T
@@ -801,12 +834,10 @@ class SamplingEngine:
             # state (fresh env state + cache rows), so the pool stays
             # healthy — nothing of the cancelled occupant survives
             self.lane = self._dispatch(
-                self._jrefill, self.lane, jnp.asarray(mask),
-                jnp.zeros((L, T, 2), jnp.uint32),
-                jnp.zeros((L,), jnp.int32),
-                jnp.full((L,), -1, jnp.int32),
-                jnp.ones((L,), jnp.float32),
-                jnp.ones((L,), jnp.float32))
+                self._jrefill, self.lane, *map(self._put, (
+                    mask, np.zeros((L, T, 2), np.uint32),
+                    np.zeros(L, np.int32), np.full(L, -1, np.int32),
+                    np.ones(L, np.float32), np.ones(L, np.float32))))
             self._occupied[mask] = False
         req = self._requests.pop(rid, None)
         if req is not None:

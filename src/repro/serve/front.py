@@ -51,6 +51,11 @@ layer the ROADMAP's "heavy traffic" story needs:
   state, queue depths, lane occupancy, arrival-rate estimates, per-engine
   latency percentiles, and retry/eviction/replay/dedup counters —
   degradation is visible, not silent.
+- **Spans.**  While a profiler session is active each runner records
+  (:mod:`repro.serve.spans`) ``serve.idle`` (waiting for a request),
+  ``serve.admit`` and per request ``serve.queue``, and ``serve.cycle``
+  around each engine cycle with ``serve.handoff`` (results to futures)
+  beside the engine's own spans inside it.
 
 Every request terminates with either a correct result or a typed
 :mod:`repro.serve.errors` error; ``tests/test_serve_front.py`` and the
@@ -69,6 +74,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import spans
 from .api import (DEFAULT_MAX_NUM_SAMPLES, SampleRequest, SampleResult,
                   result_from_engine, validate_request)
 from .errors import (BadRequest, DeadlineExceeded, EngineFailure, QueueFull,
@@ -78,10 +84,11 @@ from .scheduler import Scheduler, _engine_key
 
 class _Item:
     """One admitted request riding through a runner: the original request,
-    its completion future, and its (absolute, monotonic) deadline."""
+    its completion future, and its (absolute, monotonic) deadline.
+    ``queued_ns`` is its enqueue time on the span recorder's clock."""
 
-    __slots__ = ("req", "future", "deadline", "enqueue_t", "client",
-                 "engine_rid")
+    __slots__ = ("req", "future", "deadline", "enqueue_t", "queued_ns",
+                 "client", "engine_rid")
 
     def __init__(self, req: SampleRequest, deadline: Optional[float],
                  client: Optional[str]):
@@ -89,6 +96,7 @@ class _Item:
         self.future: Future = Future()
         self.deadline = deadline
         self.enqueue_t = time.monotonic()
+        self.queued_ns = time.perf_counter_ns()
         self.client = client
         self.engine_rid: Optional[int] = None
 
@@ -259,17 +267,40 @@ class _EngineRunner(threading.Thread):
                 self._maybe_poll_checkpoint()
                 self._maybe_autosize()
                 try:
-                    item = self.queue.get(timeout=0.05)
+                    with spans.span("serve.idle"):
+                        item = self.queue.get(timeout=0.05)
                 except queue.Empty:
                     continue
                 self._admit(item)
                 continue
             self._drive_block()
 
+    def _device_calls(self) -> Tuple[Any, int]:
+        """This runner's engine and its device-call count, to take a span's
+        ``device_calls`` from (see :meth:`_device_calls_since`)."""
+        eng = self.engine
+        return eng, (0 if eng is None else eng.counters["device_calls"])
+
+    def _device_calls_since(self, mark: Tuple[Any, int]) -> int:
+        """Device calls of the engine since ``mark``; an engine built since
+        then counts from zero."""
+        eng, n = mark
+        if self.engine is None:
+            return 0
+        return self.engine.counters["device_calls"] - (
+            n if self.engine is eng else 0)
+
     def _drive_block(self) -> None:
-        """One compiled block + the between-block bookkeeping the tentpole
-        promises: deadline enforcement, result flushing, stall detection,
-        checkpoint polling, and continuous admission."""
+        """One engine cycle (span ``serve.cycle``): a compiled block and
+        the bookkeeping between blocks — deadline enforcement, result
+        flushing, stall detection, checkpoint polling."""
+        with spans.span("serve.cycle") as sp:
+            mark = self._device_calls() if sp else None
+            self._cycle()
+            if sp:
+                sp.set(device_calls=self._device_calls_since(mark))
+
+    def _cycle(self) -> None:
         engine = self.engine
         try:
             finished = engine.step()
@@ -277,15 +308,9 @@ class _EngineRunner(threading.Thread):
             self._quarantine(e)
             return
         try:
-            for rid, res in engine.take_results().items():
-                item = self.inflight.pop(rid, None)
-                if item is None:
-                    continue
-                now = time.monotonic()
-                self.observe_latency(now - item.enqueue_t)
-                with self._lock:
-                    self.counters["completed"] += 1
-                item.complete(result_from_engine(item.req, res, rid))
+            results = engine.take_results()
+            if results:
+                self._handoff(results)
             self._enforce_deadlines()
         except Exception as e:
             self._quarantine(e)
@@ -304,6 +329,20 @@ class _EngineRunner(threading.Thread):
                 return
         self._maybe_poll_checkpoint()
 
+    def _handoff(self, results) -> None:
+        """Complete the futures of finished requests (span
+        ``serve.handoff``; their callbacks run here)."""
+        with spans.span("serve.handoff", results=len(results)):
+            for rid, res in results.items():
+                item = self.inflight.pop(rid, None)
+                if item is None:
+                    continue
+                now = time.monotonic()
+                self.observe_latency(now - item.enqueue_t)
+                with self._lock:
+                    self.counters["completed"] += 1
+                item.complete(result_from_engine(item.req, res, rid))
+
     # -- admission -----------------------------------------------------------
     def _admit_available(self) -> None:
         # while a checkpoint refresh is pending, queued items wait so they
@@ -316,6 +355,19 @@ class _EngineRunner(threading.Thread):
             self._admit(item)
 
     def _admit(self, item: _Item) -> None:
+        """Admit one item (span ``serve.admit``; its wait in the queue is
+        recorded as ``serve.queue``, up to the admission's start)."""
+        with spans.span("serve.admit",
+                        samples=item.req.num_samples) as sp:
+            mark = self._device_calls() if sp else None
+            self._admit_one(item)
+            if sp:
+                rid = -1 if item.engine_rid is None else item.engine_rid
+                sp.set(rid=rid, device_calls=self._device_calls_since(mark))
+                spans.record("serve.queue", item.queued_ns, sp.start_ns,
+                             rid=rid)
+
+    def _admit_one(self, item: _Item) -> None:
         # a poll may have flagged a refresh in this very loop iteration
         # (after _apply_pending_refresh already ran); apply it now so an
         # idle pool never admits onto params the scheduler has evicted

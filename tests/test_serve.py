@@ -67,7 +67,7 @@ def test_engine_matches_forward_rollout_under_rebatching(bitseq_setup,
     res = bitseq_engine.run()[rid]
     assert np.array_equal(res.samples, np.asarray(ref.obs[-1]))
     assert np.array_equal(res.log_rewards, np.asarray(ref.log_reward))
-    assert bitseq_engine.steps_run > 0
+    assert bitseq_engine.counters["blocks"] > 0
 
 
 def test_refilled_lanes_leak_no_state(bitseq_engine):

@@ -262,7 +262,7 @@ def test_dedup_exact_duplicate_computes_once(dedup_engine):
     """Exact duplicates share one computation: an in-flight duplicate joins
     as a waiter (no extra lane work), a post-completion duplicate is an LRU
     hit (no lane work at all), and both are bitwise the primary's result.
-    The engine step counter proves the lanes ran once."""
+    The engine's block counter proves the lanes ran once."""
     eng = dedup_engine
     kw = {"num_samples": 3, "seed": 7000}
     c0 = dict(eng.counters)
@@ -270,7 +270,7 @@ def test_dedup_exact_duplicate_computes_once(dedup_engine):
     r2 = eng.submit(**kw)               # in flight: joins r1
     assert eng.counters["dedup_joins"] == c0["dedup_joins"] + 1
     out = eng.run()
-    steps_after = eng.steps_run
+    blocks_after = eng.counters["blocks"]
     assert np.array_equal(out[r1].samples, out[r2].samples)
     assert np.array_equal(out[r1].log_rewards, out[r2].log_rewards)
     assert out[r1].dedup is False and out[r2].dedup is True
@@ -278,7 +278,7 @@ def test_dedup_exact_duplicate_computes_once(dedup_engine):
     r3 = eng.submit(**kw)               # completed: LRU hit, zero lane work
     assert eng.counters["dedup_hits"] == c0["dedup_hits"] + 1
     out3 = eng.run()
-    assert eng.steps_run == steps_after  # no block ever dispatched
+    assert eng.counters["blocks"] == blocks_after  # no block dispatched
     assert out3[r3].dedup is True
     assert np.array_equal(out3[r3].samples, out[r1].samples)
     assert np.array_equal(out3[r3].log_rewards, out[r1].log_rewards)
