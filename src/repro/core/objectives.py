@@ -26,8 +26,27 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .rollout import PolicyApply, RolloutBatch
+from .rollout import PolicyApply, RolloutBatch, _cache_engaged, _policy_entry
 from .types import masked_logprobs
+
+#: Which K/V bank each categorical teacher-forced pass was traced with:
+#: ``shared_bank`` (``Policy.apply_traj``, one bank per trajectory) or
+#: ``per_state_bank`` (``apply`` on every flattened state).  Counted at
+#: trace time, so it records the path a compiled loss took.
+counters: Dict[str, int] = {"shared_bank": 0, "per_state_bank": 0}
+
+
+def shared_bank_engaged(env, policy_apply) -> bool:
+    """Whether :func:`evaluate_trajectory` may take ``Policy.apply_traj``.
+
+    That needs the env's incremental-observation capability, resolved as
+    the cached rollout resolves it (a slot is written at most once per
+    trajectory, so every state's tokens are a subset of the trajectory's
+    full token row), and a policy that exposes ``apply_traj``.
+    """
+    policy, _ = _policy_entry(policy_apply)
+    return (_cache_engaged(env, policy, "auto")
+            and getattr(policy, "apply_traj", None) is not None)
 
 
 class TrajEval(NamedTuple):
@@ -75,19 +94,28 @@ def _evaluate_trajectory_continuous(policy, params,
 
 def evaluate_trajectory(policy_apply: PolicyApply, params,
                         batch: RolloutBatch,
-                        stop_action: Optional[int] = None) -> TrajEval:
+                        stop_action: Optional[int] = None,
+                        shared_bank: bool = False) -> TrajEval:
     """Accepts a bare ``apply(params, obs)`` callable (categorical path) or
     a full :class:`repro.core.policies.Policy` — a policy with density
     entry points (``log_prob`` non-None, see ``nn.flows``) is evaluated
     through :func:`_evaluate_trajectory_continuous` instead of the masked
-    log-softmax + gather below."""
+    log-softmax + gather below.
+
+    ``shared_bank`` (resolved by :func:`shared_bank_engaged`) evaluates
+    every stored state through ``policy_apply.apply_traj``: the same heads,
+    with each trajectory's tokens projected to K/V once."""
     if getattr(policy_apply, "log_prob", None) is not None:
         return _evaluate_trajectory_continuous(policy_apply, params, batch)
-    if hasattr(policy_apply, "apply"):
-        policy_apply = policy_apply.apply
     Tp1, B = batch.obs.shape[:2]
-    flat_obs = batch.obs.reshape((Tp1 * B,) + batch.obs.shape[2:])
-    out = policy_apply(params, flat_obs)
+    if shared_bank:
+        counters["shared_bank"] += 1
+        out = policy_apply.apply_traj(params, batch.obs)
+    else:
+        counters["per_state_bank"] += 1
+        apply = getattr(policy_apply, "apply", policy_apply)
+        out = apply(params, batch.obs.reshape((Tp1 * B,)
+                                              + batch.obs.shape[2:]))
 
     def unflat(x):
         return x.reshape((Tp1, B) + x.shape[1:])

@@ -40,6 +40,13 @@ class Policy(NamedTuple):
                     env_keys, fwd_mask, step=None,
                     eps=0.0, logit_temp=None)  -> (actions, log_pf,
                                                    out, cache)
+      apply_traj(params, obs)                          -> out over (T+1)*B
+
+    ``apply_traj`` takes a batch's stored ``(T+1, B, S)`` token observations
+    and returns the heads dict ``apply`` gives on the flattened states,
+    projecting each trajectory's tokens to K/V once instead of once per
+    state.  It holds for envs that write each token slot at most once per
+    trajectory, the same property the cached rollout relies on.
 
     ``sample_cached`` is the FUSED per-step entry: append + query + masked
     categorical sampling issued as one op from the rollout scan body / serve
@@ -67,6 +74,7 @@ class Policy(NamedTuple):
     cache_fill: Optional[Callable] = None
     query_cached: Optional[Callable] = None
     sample_cached: Optional[Callable] = None
+    apply_traj: Optional[Callable] = None
     sample: Optional[Callable] = None
     log_prob: Optional[Callable] = None
     sample_b: Optional[Callable] = None
@@ -183,17 +191,29 @@ def make_transformer_policy(vocab_size: int, max_len: int, action_dim: int,
                 + embedding_apply({"table": params["pos"]["pos"]},
                                   jnp.clip(pos, 0, max_len - 1)))
 
-    def apply(params, tokens):
-        tokens = tokens.astype(jnp.int32)
+    def _bank_heads(params, tokens, present):
+        """Heads of queries over the bank of ``tokens`` (B, S), one per
+        leading row of ``present`` (..., B, S), which masks the slots."""
         B, S = tokens.shape
         xs = _embed(params, tokens, jnp.arange(S)[None, :])
         bos = jnp.broadcast_to(params["bos"][None, None, :], (B, 1, dim))
         xs = jnp.concatenate([bos, xs], axis=1)
         mask = jnp.concatenate(
-            [jnp.ones((B, 1), bool), tokens != pad_id], axis=1)
+            [jnp.ones(present.shape[:-1] + (1,), bool), present], axis=-1)
         h = encoder_apply_bank(params["decoder"], xs, mask,
                                num_heads=num_heads)
         return heads_out(dense_apply(params["readout"], h))
+
+    def apply(params, tokens):
+        tokens = tokens.astype(jnp.int32)
+        return _bank_heads(params, tokens, tokens != pad_id)
+
+    def apply_traj(params, obs):
+        obs = obs.astype(jnp.int32)
+        # a slot is written at most once per trajectory, so its one non-pad
+        # token is its minimum over the states (pad_id is the largest id);
+        # slots no state holds stay pad, masked out of every query
+        return _bank_heads(params, jnp.min(obs, axis=0), obs != pad_id)
 
     def cache_init_fn(params, batch_size):
         x0 = jnp.broadcast_to(params["bos"][None, :], (batch_size, dim))
@@ -268,7 +288,8 @@ def make_transformer_policy(vocab_size: int, max_len: int, action_dim: int,
 
     return Policy(init, apply, cache_init=cache_init_fn,
                   apply_cached=apply_cached, cache_fill=cache_fill_fn,
-                  query_cached=query_cached, sample_cached=sample_cached)
+                  query_cached=query_cached, sample_cached=sample_cached,
+                  apply_traj=apply_traj)
 
 
 def make_phylo_policy(env, num_layers: int = 6, dim: int = 32,

@@ -11,6 +11,7 @@ replay, ...) and device-mesh execution plans (:mod:`repro.algo.plan`).
 """
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -19,7 +20,8 @@ import jax.numpy as jnp
 
 from ..envs.base import Environment
 from ..optim import adamw as optim
-from .objectives import OBJECTIVE_PARTS, OBJECTIVES, evaluate_trajectory
+from .objectives import (OBJECTIVE_PARTS, OBJECTIVES, evaluate_trajectory,
+                         shared_bank_engaged)
 from .rollout import RolloutBatch
 from .types import TrainState
 
@@ -53,16 +55,23 @@ def make_optimizer(cfg: GFNConfig):
     return optim.chain(*parts)
 
 
+def _make_eval_fn(env: Environment, policy_apply, cfg: GFNConfig):
+    """The teacher-forced pass ``(params, batch) -> TrajEval``, with the
+    shared K/V bank resolved once from the env and policy."""
+    return functools.partial(
+        evaluate_trajectory, policy_apply, stop_action=cfg.stop_action,
+        shared_bank=shared_bank_engaged(env, policy_apply))
+
+
 def make_loss_fn(env: Environment, policy_apply, cfg: GFNConfig):
     """Uniform loss over any registered objective: every entry in
     ``OBJECTIVES`` takes ``(ev, batch, params, cfg)``, so there is no
     per-objective dispatch here."""
     obj = OBJECTIVES[cfg.objective]
+    eval_fn = _make_eval_fn(env, policy_apply, cfg)
 
     def loss_fn(params, batch: RolloutBatch):
-        ev = evaluate_trajectory(policy_apply, params, batch,
-                                 stop_action=cfg.stop_action)
-        return obj(ev, batch, params, cfg)
+        return obj(eval_fn(params, batch), batch, params, cfg)
 
     return loss_fn
 
@@ -78,12 +87,10 @@ def make_loss_parts_fn(env: Environment, policy_apply, cfg: GFNConfig):
     (see :data:`repro.core.objectives.OBJECTIVE_PARTS`).
     """
     parts = OBJECTIVE_PARTS[cfg.objective]
+    eval_fn = _make_eval_fn(env, policy_apply, cfg)
 
     def parts_fn(params, batch: RolloutBatch):
-        ev = evaluate_trajectory(policy_apply, params, batch,
-                                 stop_action=cfg.stop_action)
-        num, den = parts(ev, batch, params, cfg)
-        return num, den
+        return parts(eval_fn(params, batch), batch, params, cfg)
 
     return parts_fn
 

@@ -8,6 +8,7 @@ an explicit rng).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -156,13 +157,16 @@ def _kv_heads_stacked(p: Params, x: jax.Array, num_heads: int):
 
 def _single_query_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             valid: jax.Array) -> jax.Array:
-    """q: (B, H, hd); k/v: (B, S, H, hd); valid: (B, S) bool.  Shared by the
-    cached and full paths so both reduce in the same order (parity)."""
+    """q: (..., B, H, hd); k/v: (B, S, H, hd); valid: (..., B, S) bool.
+    Leading query axes share the one (B, S) bank, broadcast in the einsums.
+    Shared by the cached and full paths so both reduce in the same order
+    (parity)."""
     hd = q.shape[-1]
-    logits = jnp.einsum('bhd,bshd->bhs', q, k) / jnp.sqrt(hd).astype(q.dtype)
-    logits = jnp.where(valid[:, None, :], logits, -1e30)
+    logits = jnp.einsum('...bhd,bshd->...bhs', q, k) / jnp.sqrt(hd).astype(
+        q.dtype)
+    logits = jnp.where(valid[..., None, :], logits, -1e30)
     attn = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum('bhs,bshd->bhd', attn, v)
+    return jnp.einsum('...bhs,bshd->...bhd', attn, v)
 
 
 def cache_init(p: Params, x0: jax.Array, capacity: int, *,
@@ -341,11 +345,22 @@ def encoder_apply_bank(p: Params, xs: jax.Array, mask: jax.Array, *,
     xs: (B, S, D) embeddings (BOS included by the caller); mask: (B, S)
     True = attendable.  Same math as the cached path — K/V from frozen
     embeddings, query through the layer stack — computed in one batch.
+
+    A mask of shape (N, B, S) runs N queries against each bank row, each
+    under its own mask: K/V are projected once per bank row and broadcast
+    over N in the attention einsums, never copied.  Returns (B, D), or
+    (N * B, D) with rows in (N, B) order.
     """
     B, S, D = xs.shape
+    lead = mask.shape[:-2]
 
     def kv_of_layer(i):
         return _kv_heads(p[f"layer_{i}"], xs, num_heads)
 
-    attend = lambda q, k, v: _single_query_attention(q, k, v, mask)
-    return _decode_query(p, num_heads, kv_of_layer, attend, B, D)
+    def attend(q, k, v):
+        o = _single_query_attention(q.reshape(lead + (B,) + q.shape[1:]),
+                                    k, v, mask)
+        return o.reshape(q.shape)
+
+    return _decode_query(p, num_heads, kv_of_layer, attend,
+                         math.prod(lead) * B, D)

@@ -1,5 +1,7 @@
 """Objective-function tests: exact identities on enumerable MDPs and
 degeneracy relations between losses."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -266,3 +268,144 @@ class TestGradients:
                 f"{name} grads not finite"
             total = sum(float(jnp.sum(jnp.abs(x))) for x in leaves)
             assert total > 0, f"{name} grads all zero"
+
+
+# ---------------------------------------------------------------------------
+# Shared K/V bank: one bank per trajectory vs `apply` on every stored state
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _seq_case(name, kind, B=8):
+    """(env, decode policy, params, batch, stop action) at test size."""
+    from repro.core.policies import make_transformer_policy
+    from repro.core.rollout import backward_rollout
+    from repro.envs.sequences import AMPEnvironment, TFBind8Environment
+    if name == "bitseq":
+        env = repro.BitSeqEnvironment(n=16, k=4)
+        max_len, stop = env.L, None
+    elif name == "tfbind8":
+        env = TFBind8Environment()
+        max_len, stop = env.length, None
+    else:
+        env = AMPEnvironment(max_len=6)
+        max_len, stop = env.max_len, env.stop_action
+    pol = make_transformer_policy(env.vocab_size, max_len, env.action_dim,
+                                  env.backward_action_dim, num_layers=2,
+                                  dim=16, num_heads=4, learn_backward=True,
+                                  arch="decode")
+    ep, pp = env.init(KEY), pol.init(KEY)
+    if kind == "early":
+        # favour the stop action so the batch's envs end at different steps
+        pp = dict(pp, readout=dict(pp["readout"], b=pp["readout"]["b"]
+                                   .at[env.stop_action].add(3.0)))
+    batch, final = forward_rollout(KEY, env, ep, pol, pp, B,
+                                   return_final_state=True)
+    if kind == "backward":
+        batch = backward_rollout(jax.random.PRNGKey(1), env, ep, pol, pp,
+                                 final, collect=True).batch
+    return env, pol, pp, batch, stop
+
+
+@pytest.mark.parametrize("name,objective,kind", [
+    (name, obj, "forward") for name in ("bitseq", "tfbind8", "amp")
+    for obj in ("tb", "db", "subtb")] + [
+    ("amp", "tb", "early"), ("bitseq", "db", "backward"),
+    ("amp", "subtb", "backward")])
+def test_shared_bank_matches_per_state_apply(name, objective, kind):
+    """The shared bank gives the per-state ``apply``'s TrajEval, loss and
+    gradients to fp32 tolerance: forward rollouts (bitseq writes anywhere,
+    TFBind8 appends, AMP has a stop action), a batch whose envs end at
+    different steps, and backward-built (replayed) batches."""
+    from repro.core.objectives import OBJECTIVES
+    from repro.core.trainer import GFNConfig
+    env, pol, pp, batch, stop = _seq_case(name, kind)
+    if kind == "early":
+        lengths = np.asarray(batch.valid.sum(0))
+        assert len(set(lengths.tolist())) > 1, lengths
+    cfg = GFNConfig(objective=objective, stop_action=stop)
+
+    def loss_and_grads(policy_apply, shared):
+        def loss(p):
+            ev = evaluate_trajectory(policy_apply, p, batch, stop,
+                                     shared_bank=shared)
+            return OBJECTIVES[objective](ev, batch, p, cfg), ev
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(pp)
+
+    (loss_s, ev_s), g_s = loss_and_grads(pol, True)
+    (loss_p, ev_p), g_p = loss_and_grads(pol.apply, False)
+    for field, a, b in zip(ev_s._fields, ev_s, ev_p):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5, err_msg=field)
+    np.testing.assert_allclose(float(loss_s), float(loss_p), rtol=1e-5)
+    flat_p = dict(jax.tree_util.tree_leaves_with_path(g_p))
+    for path, a in jax.tree_util.tree_leaves_with_path(g_s):
+        b = np.asarray(flat_p[path])
+        np.testing.assert_allclose(
+            np.asarray(a), b, rtol=1e-4, atol=1e-5 * np.max(np.abs(b)),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def _bypass_case(name):
+    from repro.core.policies import make_transformer_policy
+    from repro.envs import ObservationTransform
+    from repro.envs.sequences import QM9Environment
+    if name == "hypergrid_mlp":
+        env, _ = make_hypergrid(2, 4)
+        return env, make_mlp_policy(env.obs_dim, env.action_dim,
+                                    env.backward_action_dim, hidden=(16,),
+                                    learn_backward=True)
+
+    class Rewritten(ObservationTransform):
+        def transform_obs(self, obs):
+            return obs + 0
+
+    env = (Rewritten(repro.BitSeqEnvironment(n=16, k=4))
+           if name == "bitseq_obs_transform" else QM9Environment())
+    width = env.L if name == "bitseq_obs_transform" else env.length
+    return env, make_transformer_policy(
+        env.vocab_size, width, env.action_dim, env.backward_action_dim,
+        num_layers=2, dim=16, num_heads=4, learn_backward=True,
+        arch="decode")
+
+
+@pytest.mark.parametrize("name", ["hypergrid_mlp", "bitseq_obs_transform",
+                                  "qm9_decode"])
+def test_per_state_bank_bypass(name):
+    """An MLP policy, an observation-rewriting transform and QM9's prepend
+    edits keep the per-state pass, with the seed's loss."""
+    from repro.core import objectives
+    from repro.core.trainer import GFNConfig, make_loss_fn
+    env, pol = _bypass_case(name)
+    ep, pp = env.init(KEY), pol.init(KEY)
+    batch = forward_rollout(KEY, env, ep, pol, pp, 8)
+    cfg = GFNConfig(objective="db")
+    assert not objectives.shared_bank_engaged(env, pol)
+    before = dict(objectives.counters)
+    loss = make_loss_fn(env, pol, cfg)(pp, batch)
+    assert objectives.counters["per_state_bank"] == \
+        before["per_state_bank"] + 1
+    assert objectives.counters["shared_bank"] == before["shared_bank"]
+    seed = db_loss(evaluate_trajectory(pol.apply, pp, batch), batch)
+    np.testing.assert_array_equal(np.asarray(loss), np.asarray(seed))
+
+
+@pytest.mark.parametrize("recipe,env_kw,path", [
+    ("bitseq_tb", {"n": 16, "k": 4}, "shared_bank"),
+    ("hypergrid_subtb", {"dim": 2, "side": 4}, "per_state_bank")])
+def test_train_step_bank_path(recipe, env_kw, path):
+    """A recipe's compiled training step records the bank its loss took."""
+    from repro import recipes
+    from repro.algo import TrainLoop
+    from repro.core import objectives
+    from repro.recipes.base import RunOptions
+    r = recipes.get(recipe)
+    env = r.make_env(**env_kw)
+    ep = env.init(KEY)
+    cfg = r.make_config(env, RunOptions(num_envs=4))
+    loop = TrainLoop(env, ep, r.make_policy(env), cfg)
+    state = loop.init(KEY)
+    before = dict(objectives.counters)
+    jax.eval_shape(loop.step_fn, state)
+    other = ({"shared_bank", "per_state_bank"} - {path}).pop()
+    assert objectives.counters[path] == before[path] + 1
+    assert objectives.counters[other] == before[other]
