@@ -15,6 +15,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..models import mla_moe
 from ..nn.core import (dense_apply, dense_init, embedding_apply,
                        embedding_init, mlp_apply, mlp_init, normal_init)
 from ..nn.transformer import (cache_fill, cache_init, decode_encoder_init,
@@ -331,3 +332,78 @@ def make_phylo_policy(env, num_layers: int = 6, dim: int = 32,
                 "log_flow": log_flow}
 
     return Policy(init, apply)
+
+
+def make_lm_policy(model_cfg, prompt, max_len: int, pad_id: int) -> Policy:
+    """A language model (``models.mla_moe``) as the policy of
+    :class:`repro.envs.lm_tokens.LMTokenEnvironment`: the next-token
+    distribution after ``prompt`` and the continuation so far.
+
+    Observations are continuations (N, ``max_len``) padded with ``pad_id``.  ``apply``
+    runs one causal pass per state; ``apply_traj`` one causal pass per
+    trajectory over prompt + continuation, reading every state's logits at
+    its last position (the pass is causal, so each is the state's own).
+    The rollout's cache holds the prompt but its last token
+    (``cache_fill``); decode step t feeds the state's last token (the
+    prompt's last at t = 0) at position ``P - 1 + t`` through the latent
+    cache.  ``log_z`` is one scalar: there is one prompt, so it is exact.
+    """
+    prompt = jnp.asarray(prompt, jnp.int32)
+    P = prompt.shape[0]
+
+    def init(key):
+        p = mla_moe.init_params(key, model_cfg)
+        p["log_z"] = jnp.zeros((), jnp.float32)
+        return p
+
+    def _tokens(obs):
+        obs = obs.astype(jnp.int32)
+        cont = jnp.where(obs == pad_id, 0, obs)
+        return jnp.concatenate(
+            [jnp.broadcast_to(prompt, (obs.shape[0], P)), cont], axis=1)
+
+    def apply(params, obs):
+        h = mla_moe.hidden(params, _tokens(obs), model_cfg)
+        last = P - 1 + jnp.sum(obs != pad_id, axis=-1)
+        h = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+        return {"logits": mla_moe.logits(params, h)}
+
+    def apply_traj(params, obs):
+        # each position is written once per trajectory, so a trajectory's
+        # tokens are its states' elementwise minimum (pad_id is the largest)
+        Tp1, B, T = obs.shape
+        h = mla_moe.hidden(params, _tokens(jnp.min(obs, axis=0)), model_cfg)
+        h = jnp.swapaxes(h[:, P - 1:P + T], 0, 1)            # (T+1, B, D)
+        return {"logits": mla_moe.logits(params, h).reshape(Tp1 * B, -1)}
+
+    def cache_fill_fn(params, cache, tokens):
+        return mla_moe.prefill(params, cache, tokens.astype(jnp.int32),
+                               model_cfg)
+
+    def cache_init_fn(params, batch_size):
+        cap = P + max_len
+        cache = mla_moe.cache_init(model_cfg, batch_size, cap)
+        return cache_fill_fn(params, cache,
+                             jnp.broadcast_to(prompt[:-1],
+                                              (batch_size, P - 1)))
+
+    def apply_cached(params, cache, token, pos, length, step=None):
+        t = jnp.max(length) if step is None else step
+        tok = jnp.where(length == 0, prompt[-1], token.astype(jnp.int32))
+        logits, cache = mla_moe.decode(params, cache, tok, P - 1 + t,
+                                       model_cfg)
+        return {"logits": logits}, cache
+
+    def sample_cached(params, cache, token, pos, length, env_keys, fwd_mask,
+                      step=None, eps=0.0, logit_temp=None):
+        out, cache = apply_cached(params, cache, token, pos, length,
+                                  step=step)
+        logits = out["logits"] if logit_temp is None \
+            else out["logits"] * logit_temp[:, None]
+        actions, log_pf = sample_masked_per_env(None, logits, fwd_mask,
+                                                eps=eps, env_keys=env_keys)
+        return actions, log_pf, out, cache
+
+    return Policy(init, apply, cache_init=cache_init_fn,
+                  apply_cached=apply_cached, cache_fill=cache_fill_fn,
+                  sample_cached=sample_cached, apply_traj=apply_traj)

@@ -59,6 +59,11 @@ def _tl_kernel(logits_ref, act_ref, mask_ref, valid_ref, out_ref, step_ref,
         out_ref[0] = acc_scr[...]
 
 
+#: largest (block_t, A) float32 logits tile: 1 MiB, a sixteenth of the
+#: 16 MiB scoped VMEM a v5e kernel gets by default
+_TILE_BYTES = 1 << 20
+
+
 def traj_logprob_pallas(logits: jax.Array, actions: jax.Array,
                         mask: jax.Array, valid: jax.Array, *,
                         block_t: int = 128,
@@ -69,10 +74,14 @@ def traj_logprob_pallas(logits: jax.Array, actions: jax.Array,
     log-probs (DB), zero where ``valid == 0``.
 
     The time axis is padded to a ``block_t`` multiple internally; padded
-    steps carry ``valid == 0`` and contribute nothing.
+    steps carry ``valid == 0`` and contribute nothing.  ``block_t`` is cut
+    to a multiple of 8 that keeps one (block_t, A) float32 tile within
+    ``_TILE_BYTES``, so that wide action spaces (an LM vocabulary) fit the
+    kernel's scoped VMEM with its double buffers and temporaries.
     """
     B, T, A = logits.shape
-    block_t = min(block_t, round_up(max(T, 1), 8))
+    fit = max(8, _TILE_BYTES // (4 * A) // 8 * 8)
+    block_t = min(block_t, round_up(max(T, 1), 8), fit)
     pad_t = (-T) % block_t
     actions = actions.astype(jnp.int32)[..., None]
     maski = (mask != 0).astype(jnp.int32)

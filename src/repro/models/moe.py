@@ -12,6 +12,12 @@ C = ceil(topk * Tg / E * capacity_factor) — total memory T * topk * Tg * cf,
 independent of E, and sharded over the data axis via the leading G dim.
 Overflowing tokens are dropped (contribute only via the shared expert /
 residual), the standard GShard trade-off.
+
+The ``mla_moe`` family (Moonlight / DeepSeek-V3) uses the held-expert
+layer at the end of this module instead: a sigmoid ``noaux_tc`` router
+over every routed expert (``sigmoid_topk_route``) and the part of the
+routed sum that the experts this chip holds give, with no token dropped
+(``held_experts``).
 """
 from __future__ import annotations
 
@@ -129,3 +135,63 @@ def moe_block_apply(p, x, cfg, positions, attention_sublayer, rmsnorm_fn,
     m, aux = moe_mlp(p, rmsnorm_fn(p["ln2"], x), cfg, group_size)
     x = x + m
     return x, new_cache, aux
+
+
+# ---------------------------------------------------------------------------
+# Held-expert dropless layer (DeepSeek-V3 / Moonlight routing)
+# ---------------------------------------------------------------------------
+
+#: What each traced held-expert layer was: ``held_dropless`` counts the
+#: layers, ``experts_held`` the experts they held, summed.  Counted at trace
+#: time, as ``core.objectives.counters``.
+counters: Dict[str, int] = {"held_dropless": 0, "experts_held": 0}
+
+
+def sigmoid_topk_route(x: jax.Array, w_router: jax.Array, bias: jax.Array,
+                       k: int, scaling: float) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek-V3 ``noaux_tc`` routing with one expert group: scores
+    ``s = sigmoid(x W_r)`` over every routed expert, the top ``k`` of
+    ``s + bias`` chosen, their weights ``s`` renormalised to sum to 1 and
+    scaled by ``scaling``.  The bias only steers the choice and takes no
+    gradient.  Returns expert ids and weights, each (T, k)."""
+    s = jax.nn.sigmoid((x @ w_router).astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scaling
+    return idx, w
+
+
+def held_experts(p: Params, x: jax.Array, idx: jax.Array, weights: jax.Array,
+                 first: int) -> jax.Array:
+    """This chip's part of a routed-expert layer: the experts
+    ``[first, first + G)`` that ``p`` holds, each a SiLU MLP, applied to
+    the tokens routed to them, weighted and summed per token.  No token is
+    dropped: every (token, choice) pair that lands on a held expert runs.
+
+    ``p``: ``gate``/``up`` ``w`` (D, G, F) and ``down`` ``w`` (F, G, D) —
+    fan-in first, experts second; x (T, D); idx, weights (T, k) over all
+    routed experts.  The pairs are sorted by held expert (the rest last)
+    and the held experts run as one grouped matmul per projection
+    (``lax.ragged_dot``) over the sorted rows; rows past the held count
+    are not computed, and are held at zero in both directions."""
+    G = p["gate"]["w"].shape[1]
+    counters["held_dropless"] += 1
+    counters["experts_held"] += G
+    T, k = idx.shape
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < G)
+    group = jnp.where(held, local, G)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=G + 1)[:G].astype(jnp.int32)
+    rows = order // k                                   # token of each pair
+    # the TPU kernel leaves rows past the groups unwritten, in its outputs
+    # and in its gradients' rows: keep them out of both directions
+    live = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
+    keep = lambda a: jnp.where(live, a, 0.0)
+    gw = lambda name: jnp.transpose(p[name]["w"], (1, 0, 2))  # (G, in, out)
+    xs = keep(x[rows])
+    h = jax.nn.silu(keep(jax.lax.ragged_dot(xs, gw("gate"), sizes))) \
+        * keep(jax.lax.ragged_dot(xs, gw("up"), sizes))
+    y = keep(jax.lax.ragged_dot(h, gw("down"), sizes))
+    w = weights.reshape(-1)[order].astype(y.dtype)
+    return jnp.zeros_like(x).at[rows].add(y * w[:, None])

@@ -7,6 +7,6 @@ from .base import RECIPES, Recipe, RunOptions, get, names, register
 
 # importing the catalog modules registers their recipes
 from . import (box, dag, hypergrid, ising,  # noqa: F401  (side effects)
-               phylo, seqs)
+               lm, phylo, seqs)
 
 __all__ = ["Recipe", "RunOptions", "RECIPES", "register", "get", "names"]

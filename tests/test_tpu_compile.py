@@ -9,6 +9,11 @@ width-64 8-head decode transformer) at the paper's 16 envs and at 256, and
 the ``hypergrid_subtb`` paper grid (20^4: 77-step trajectories).  Nothing
 runs; a compile that passes says the compiler accepts the kernel, not that
 its results are right (the interpret-mode oracle tests cover that).
+The ``lm_tb`` cell's shapes are compiled too: ``traj_logprob`` over a
+20480-id vocabulary slice (32 continuations of 64 tokens) and the held
+experts' grouped matmul (``models.moe.held_experts``: 8 of Moonlight's 64
+experts, 2048 -> 1408 -> 2048) on the teacher-forced pass's 32 x 320
+tokens, forward and backward.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test collection must be the
@@ -31,6 +36,10 @@ WORDS, ACTIONS = 15, 15 * 256          # L = n / k positions, L * 2^k actions
 CAPACITY = WORDS + 1                   # cache slots: BOS + one per word
 SUBTB_STATES = 4 * 19 + 2              # T + 1 for dim=4, side=20
 BATCHES = (16, 256)                    # paper num_envs, and a chip-filling one
+# lm_tb (recipes/lm.py): 32 continuations of 64 tokens after a 256-token
+# prompt, over a 20480-id slice; Moonlight-16B-A3B expert widths
+LM_ENVS, LM_STEPS, LM_VOCAB, LM_PROMPT = 32, 64, 20480, 256
+D_MODEL, EXPERT_FF, EXPERTS, HELD, TOP_K = 2048, 1408, 64, 8, 6
 
 
 @pytest.fixture(scope="module")
@@ -118,6 +127,38 @@ def test_traj_logprob_compiles(spec, batch, actions):
                                                 interpret=False),
         spec((B, T, actions)), spec((B, T), jnp.int32),
         spec((B, T, actions), jnp.bool_), spec((B, T), jnp.bool_))
+
+
+@pytest.mark.parametrize("actions", [LM_VOCAB, 1], ids=["fwd", "bwd"])
+def test_traj_logprob_compiles_at_lm_vocab(spec, actions):
+    B, T = LM_ENVS, LM_STEPS
+    _assert_kernel(
+        lambda lg, a, m, v: traj_logprob_pallas(lg, a, m, v,
+                                                interpret=False),
+        spec((B, T, actions)), spec((B, T), jnp.int32),
+        spec((B, T, actions), jnp.bool_), spec((B, T), jnp.bool_))
+
+
+def test_held_experts_grouped_matmul_compiles(spec):
+    """Forward and backward of the held experts on the teacher-forced
+    pass's tokens: the grouped matmuls forward and their gradient products
+    backward lower to the TPU's ragged-dot kernels."""
+    from repro.models import moe
+    T = LM_ENVS * (LM_PROMPT + LM_STEPS)
+
+    def loss(p, x, idx, w):
+        return jnp.sum(moe.held_experts(p, x, idx, w, 0))
+
+    p = {"gate": {"w": spec((D_MODEL, HELD, EXPERT_FF))},
+         "up": {"w": spec((D_MODEL, HELD, EXPERT_FF))},
+         "down": {"w": spec((EXPERT_FF, HELD, D_MODEL))}}
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        p, spec((T, D_MODEL)), spec((T, TOP_K), jnp.int32),
+        spec((T, TOP_K))).compile().as_text()
+    calls = [l for l in text.splitlines()
+             if "custom-call(" in l and "%ragged-dot-" in l
+             and "metadata" not in l.split("=")[0]]
+    assert len(calls) >= 6, calls
 
 
 @pytest.mark.parametrize("batch", BATCHES)
