@@ -339,14 +339,17 @@ def make_lm_policy(model_cfg, prompt, max_len: int, pad_id: int) -> Policy:
     :class:`repro.envs.lm_tokens.LMTokenEnvironment`: the next-token
     distribution after ``prompt`` and the continuation so far.
 
-    Observations are continuations (N, ``max_len``) padded with ``pad_id``.  ``apply``
-    runs one causal pass per state; ``apply_traj`` one causal pass per
-    trajectory over prompt + continuation, reading every state's logits at
-    its last position (the pass is causal, so each is the state's own).
-    The rollout's cache holds the prompt but its last token
-    (``cache_fill``); decode step t feeds the state's last token (the
-    prompt's last at t = 0) at position ``P - 1 + t`` through the latent
-    cache.  ``log_z`` is one scalar: there is one prompt, so it is exact.
+    Observations are continuations (N, ``max_len``) padded with ``pad_id``.
+    Every row continues the one prompt, so each pass runs the prompt but
+    its last token once, at batch 1 (``mla_moe.shared_prefix``), and the
+    rows attend over its latents: ``apply`` runs ``[prompt[-1]] +
+    continuation`` per state, ``apply_traj`` per trajectory (the pass is
+    causal, so position t is state t's own), both at positions ``P - 1``
+    on; ``cache_init`` broadcasts the latents into the rollout's cache.
+    Decode step t feeds the state's last token (the prompt's last at t = 0)
+    at position ``P - 1 + t`` through the latent cache.  Gradients reach
+    the prompt's pass summed over the rows.  ``log_z`` is one scalar:
+    there is one prompt, so it is exact.
     """
     prompt = jnp.asarray(prompt, jnp.int32)
     P = prompt.shape[0]
@@ -356,24 +359,27 @@ def make_lm_policy(model_cfg, prompt, max_len: int, pad_id: int) -> Policy:
         p["log_z"] = jnp.zeros((), jnp.float32)
         return p
 
-    def _tokens(obs):
+    def _hidden(params, obs):
+        """Rows ``[prompt[-1]] + continuation`` (N, max_len + 1) after the
+        prompt's shared prefix: hidden states at positions P - 1 on."""
         obs = obs.astype(jnp.int32)
         cont = jnp.where(obs == pad_id, 0, obs)
-        return jnp.concatenate(
-            [jnp.broadcast_to(prompt, (obs.shape[0], P)), cont], axis=1)
+        rows = jnp.concatenate(
+            [jnp.broadcast_to(prompt[-1], (obs.shape[0], 1)), cont], axis=1)
+        pre = mla_moe.shared_prefix(params, prompt[:-1], model_cfg)
+        return mla_moe.hidden(params, rows, model_cfg, prefix=pre)
 
     def apply(params, obs):
-        h = mla_moe.hidden(params, _tokens(obs), model_cfg)
-        last = P - 1 + jnp.sum(obs != pad_id, axis=-1)
+        h = _hidden(params, obs)
+        last = jnp.sum(obs != pad_id, axis=-1)
         h = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
         return {"logits": mla_moe.logits(params, h)}
 
     def apply_traj(params, obs):
         # each position is written once per trajectory, so a trajectory's
         # tokens are its states' elementwise minimum (pad_id is the largest)
-        Tp1, B, T = obs.shape
-        h = mla_moe.hidden(params, _tokens(jnp.min(obs, axis=0)), model_cfg)
-        h = jnp.swapaxes(h[:, P - 1:P + T], 0, 1)            # (T+1, B, D)
+        Tp1, B, _ = obs.shape
+        h = jnp.swapaxes(_hidden(params, jnp.min(obs, axis=0)), 0, 1)
         return {"logits": mla_moe.logits(params, h).reshape(Tp1 * B, -1)}
 
     def cache_fill_fn(params, cache, tokens):
@@ -381,11 +387,9 @@ def make_lm_policy(model_cfg, prompt, max_len: int, pad_id: int) -> Policy:
                                model_cfg)
 
     def cache_init_fn(params, batch_size):
-        cap = P + max_len
-        cache = mla_moe.cache_init(model_cfg, batch_size, cap)
-        return cache_fill_fn(params, cache,
-                             jnp.broadcast_to(prompt[:-1],
-                                              (batch_size, P - 1)))
+        cache = mla_moe.cache_init(model_cfg, batch_size, P + max_len)
+        return mla_moe.load(cache, mla_moe.shared_prefix(
+            params, prompt[:-1], model_cfg))
 
     def apply_cached(params, cache, token, pos, length, step=None):
         t = jnp.max(length) if step is None else step

@@ -15,7 +15,9 @@ The cache holds only ``c`` (after its norm) and ``k_pe`` (after RoPE): rank
 + rope numbers per token and layer, not per head.  Two paths:
 
 - ``expanded``: prefill and the teacher-forced pass up-project every
-  token's ``c`` to per-head keys and values and attend causally;
+  token's ``c`` to per-head keys and values and attend causally, after an
+  optional prefix shared by every row (its latents at batch 1, up-projected
+  once);
 - ``latent_decode``: one new token per row attends over the latent cache
   with ``W_kv_b`` absorbed into the query and the output (``q_nope W_uk``
   scores against ``c``; the weighted ``c`` is mapped by ``W_uv``), so a
@@ -37,9 +39,12 @@ from .layers import rmsnorm
 
 #: Which attention path each traced MLA call took: ``latent_decode`` (one
 #: token per row against the latent cache, ``W_kv_b`` absorbed) or
-#: ``expanded`` (keys and values up-projected from ``c``).  Counted at
-#: trace time, as ``core.objectives.counters``.
-counters: Dict[str, int] = {"latent_decode": 0, "expanded": 0}
+#: ``expanded`` (keys and values up-projected from ``c``); and
+#: ``shared_prefix``, each traced pass that runs a prompt once at batch 1
+#: for every row (``models.mla_moe.shared_prefix``).  Counted at trace
+#: time, as ``core.objectives.counters``.
+counters: Dict[str, int] = {"latent_decode": 0, "expanded": 0,
+                            "shared_prefix": 0}
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -87,24 +92,38 @@ def _scale(cfg):
     return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
 
 
-def mla_expanded(p, x, positions, cfg):
+def mla_expanded(p, x, positions, cfg, prefix=None):
     """Causal attention over ``x`` (B, S, D) at ``positions`` (S,), with
-    keys and values up-projected from the latents.  Returns the output
-    (B, S, D) and the latents (c, k_pe) the cache keeps."""
+    keys and values up-projected from the latents, after ``prefix``: the
+    latents (c (1, P', rank), k_pe (1, P', rope)) of positions ``0..P'-1``
+    shared by every row (none by default), whose keys and values are
+    up-projected once and scored apart from the rows' own, under one
+    softmax, so they are never copied per row.  ``positions`` then start at
+    ``P'``.  Returns the output (B, S, D) and the rows' latents (c, k_pe)
+    the cache keeps."""
     counters["expanded"] += 1
     H, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
     B, S, _ = x.shape
+    w_kv_b = p["kv_b"]["w"]
     q_nope, q_pe = _queries(p, x, positions, cfg)
     c, k_pe = latents(p, x, positions, cfg)
-    kv = (c @ p["kv_b"]["w"]).reshape(B, S, H, -1)
-    k_nope, v = kv[..., :nope], kv[..., nope:]
-    s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
-         + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe)) * _scale(cfg)
+    c_pre, kpe_pre = (c[0, :0], k_pe[0, :0]) if prefix is None else \
+        (prefix[0][0], prefix[1][0])
+    n = c_pre.shape[0]
+    kv = (c @ w_kv_b).reshape(B, S, H, -1)
+    kv_pre = (c_pre @ w_kv_b).reshape(n, H, kv.shape[-1])
     causal = positions[:, None] >= positions[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
+    s = jnp.concatenate(
+        [jnp.einsum("bqhd,khd->bhqk", q_nope, kv_pre[..., :nope])
+         + jnp.einsum("bqhr,kr->bhqk", q_pe, kpe_pre),
+         jnp.where(causal, jnp.einsum("bqhd,bkhd->bhqk", q_nope,
+                                      kv[..., :nope])
+                   + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe), -jnp.inf)],
+        axis=-1) * _scale(cfg)
     a = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(B, S, -1)
-    return o @ p["o"]["w"], (c, k_pe)
+    o = (jnp.einsum("bhqk,khd->bqhd", a[..., :n], kv_pre[..., nope:])
+         + jnp.einsum("bhqk,bkhd->bqhd", a[..., n:], kv[..., nope:]))
+    return o.reshape(B, S, -1) @ p["o"]["w"], (c, k_pe)
 
 
 def mla_latent_decode(p, x, slot, c_cache, kpe_cache, cfg):

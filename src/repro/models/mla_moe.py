@@ -14,7 +14,10 @@ give the logits.
 Parameters keep one ``w`` per projection with its fan-in first (experts'
 ``w`` are (in, G, out)).  ``hidden`` is the teacher-forced pass (every
 layer recomputed in the backward pass); ``prefill`` and
-``decode`` run through the latent cache (``models.mla``).
+``decode`` run through the latent cache (``models.mla``).  Rows that all
+continue one prompt run it once: ``shared_prefix`` passes the prompt at
+batch 1 and returns each layer's latents, which ``hidden(prefix=...)``
+attends over and ``load`` broadcasts into a cache.
 """
 from __future__ import annotations
 
@@ -133,29 +136,41 @@ def embed(params: Params, tokens: jax.Array) -> jax.Array:
 
 
 def hidden(params: Params, tokens: jax.Array, cfg: MLAMoEConfig,
-           with_latents: bool = False):
+           with_latents: bool = False, prefix=None):
     """The teacher-forced pass: tokens (B, S) at positions 0..S-1 to final
     normed hidden states (B, S, D); with ``with_latents`` also each layer's
-    cache entries (c (L, B, S, rank), k_pe (L, B, S, rope))."""
-    positions = jnp.arange(tokens.shape[1])
+    cache entries (c (L, B, S, rank), k_pe (L, B, S, rope)).  After a
+    ``prefix`` (``shared_prefix``'s latents of P' tokens) the tokens sit at
+    positions P'..P'+S-1 and attend over it in every layer."""
+    start = 0 if prefix is None else prefix[0].shape[2]
+    positions = start + jnp.arange(tokens.shape[1])
 
     @jax.checkpoint
-    def layer(lp, x):
+    def layer(lp, x, pre):
         a, lat = mla.mla_expanded(lp["attn"], _norm(lp["attn_norm"], x, cfg),
-                                  positions, cfg)
+                                  positions, cfg, pre)
         x = x + a
         return x + ffn(lp, _norm(lp["ffn_norm"], x, cfg), cfg), lat
 
     x = embed(params, tokens)
     cs, kpes = [], []
-    for lp in _layers(params):
-        x, (c, k_pe) = layer(lp, x)
+    for i, lp in enumerate(_layers(params)):
+        pre = None if prefix is None else (prefix[0][i], prefix[1][i])
+        x, (c, k_pe) = layer(lp, x, pre)
         cs.append(c)
         kpes.append(k_pe)
     h = _norm(params["final_norm"], x, cfg)
     if with_latents:
         return h, (jnp.stack(cs), jnp.stack(kpes))
     return h
+
+
+def shared_prefix(params: Params, tokens: jax.Array, cfg: MLAMoEConfig):
+    """One prompt shared by every row, tokens (P',) at positions 0..P'-1,
+    run once at batch 1: each layer's latents (c (L, 1, P', rank), k_pe
+    (L, 1, P', rope)), for ``hidden(prefix=...)`` and ``load``."""
+    mla.counters["shared_prefix"] += 1
+    return hidden(params, tokens[None], cfg, with_latents=True)[1]
 
 
 def logits(params: Params, h: jax.Array) -> jax.Array:
@@ -168,12 +183,19 @@ def cache_init(cfg: MLAMoEConfig, batch: int, capacity: int):
             "k_pe": jnp.zeros((L, batch, capacity, cfg.qk_rope_head_dim))}
 
 
+def load(cache, latents):
+    """Write latents (c (L, B or 1, S, rank), k_pe) at slots 0..S-1 of every
+    row of the cache; a batch-1 prefix is broadcast to the rows."""
+    def put(buf, new):
+        new = jnp.broadcast_to(new, buf.shape[:2] + new.shape[2:])
+        return jax.lax.dynamic_update_slice_in_dim(buf, new, 0, axis=2)
+    return {"c": put(cache["c"], latents[0]),
+            "k_pe": put(cache["k_pe"], latents[1])}
+
+
 def prefill(params: Params, cache, tokens: jax.Array, cfg: MLAMoEConfig):
     """Load tokens (B, S) at positions 0..S-1 into the latent cache."""
-    _, (c, k_pe) = hidden(params, tokens, cfg, with_latents=True)
-    put = lambda buf, new: jax.lax.dynamic_update_slice_in_dim(buf, new, 0,
-                                                               axis=2)
-    return {"c": put(cache["c"], c), "k_pe": put(cache["k_pe"], k_pe)}
+    return load(cache, hidden(params, tokens, cfg, with_latents=True)[1])
 
 
 def decode(params: Params, cache, token: jax.Array, slot,

@@ -178,20 +178,28 @@ def held_experts(p: Params, x: jax.Array, idx: jax.Array, weights: jax.Array,
     counters["held_dropless"] += 1
     counters["experts_held"] += G
     T, k = idx.shape
-    local = idx.reshape(-1) - first
+    # XLA's TPU compiler gives its grouped-matmul kernel only a row count
+    # that is a multiple of 8, and expands any other densely over every
+    # held expert; on a v5e that expansion's results are wrong (its
+    # gradients at the default precision by ~100%).  Pad with pairs that
+    # land on no held expert
+    pad = -(T * k) % 8
+    local = jnp.concatenate([idx.reshape(-1) - first,
+                             jnp.full((pad,), -1, idx.dtype)])
     held = (local >= 0) & (local < G)
     group = jnp.where(held, local, G)
     order = jnp.argsort(group, stable=True)
     sizes = jnp.bincount(group, length=G + 1)[:G].astype(jnp.int32)
-    rows = order // k                                   # token of each pair
+    rows = jnp.minimum(order // k, T - 1)               # token of each pair
     # the TPU kernel leaves rows past the groups unwritten, in its outputs
     # and in its gradients' rows: keep them out of both directions
-    live = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
+    live = (jnp.arange(T * k + pad) < jnp.sum(sizes))[:, None]
     keep = lambda a: jnp.where(live, a, 0.0)
     gw = lambda name: jnp.transpose(p[name]["w"], (1, 0, 2))  # (G, in, out)
     xs = keep(x[rows])
     h = jax.nn.silu(keep(jax.lax.ragged_dot(xs, gw("gate"), sizes))) \
         * keep(jax.lax.ragged_dot(xs, gw("up"), sizes))
     y = keep(jax.lax.ragged_dot(h, gw("down"), sizes))
-    w = weights.reshape(-1)[order].astype(y.dtype)
+    w = jnp.concatenate([weights.reshape(-1), jnp.zeros((pad,), weights.dtype)]
+                        )[order].astype(y.dtype)
     return jnp.zeros_like(x).at[rows].add(y * w[:, None])

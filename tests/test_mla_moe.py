@@ -118,6 +118,105 @@ def test_tb_loss_and_gradients_match_reference(env, policy):
     assert not np.any(grads["layers"]["layer_1"]["moe"]["router"]["bias"])
 
 
+def per_row_apply_traj(env, params, obs):
+    """``apply_traj`` as one causal pass over prompt + continuation per
+    trajectory (``mla_moe.hidden`` on the broadcast prompt), at
+    ``highest`` precision: the pass the shared prefix replaces."""
+    Tp1, n, _ = obs.shape
+    cont = jnp.min(obs, axis=0)
+    tokens = jnp.concatenate([jnp.broadcast_to(jnp.asarray(env.prompt),
+                                               (n, P)),
+                              jnp.where(cont == env.pad, 0, cont)], axis=1)
+    with jax.default_matmul_precision("highest"):
+        h = mla_moe.hidden(params, tokens, CFG)[:, P - 1:P + T]
+        return {"logits": mla_moe.logits(params, jnp.swapaxes(h, 0, 1))
+                .reshape(Tp1 * n, -1)}
+
+
+def test_shared_prefix_pass_matches_per_row_pass(env, policy):
+    """The teacher-forced pass after the prompt's batch-1 prefix gives the
+    per-row pass's logits, and the TB loss through it the per-row loss and
+    every parameter's gradient (the prompt's share summed over the rows)."""
+    params = seeded_params(CFG, 3)
+    env_params = env.init(jax.random.PRNGKey(1))
+    batch = jax.jit(lambda p: forward_rollout(
+        jax.random.PRNGKey(8), env, env_params, policy, p, B))(params)
+    got = jax.jit(policy.apply_traj)(params, batch.obs)["logits"]
+    want = jax.jit(lambda p, o: per_row_apply_traj(env, p, o))(
+        params, batch.obs)["logits"]
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    per_row = policy._replace(
+        apply_traj=lambda p, o: per_row_apply_traj(env, p, o))
+    cfg = GFNConfig(objective="tb")
+    loss, grads = jax.jit(jax.value_and_grad(make_loss_fn(env, policy, cfg)))(
+        params, batch)
+    want, want_g = jax.jit(jax.value_and_grad(
+        make_loss_fn(env, per_row, cfg)))(params, batch)
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(want_g)):
+        scale = float(jnp.max(jnp.abs(w))) or 1.0
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-4 * scale,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_cache_init_is_the_per_row_prefill(env, policy):
+    """The rollout's cache, filled from one batch-1 pass of the prompt,
+    holds in every row what the per-row prefill writes there (decoding
+    from it is checked against the reference above)."""
+    params = seeded_params(CFG, 4)
+    got = jax.jit(policy.cache_init, static_argnums=1)(params, B)
+    prompt = jnp.broadcast_to(jnp.asarray(env.prompt)[:-1], (B, P - 1))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda p: mla_moe.prefill(
+            p, mla_moe.cache_init(CFG, B, P + T), prompt, CFG))(params)
+    for k in ("c", "k_pe"):
+        np.testing.assert_allclose(got[k], want[k], rtol=1e-5, atol=1e-6,
+                                   err_msg=k)
+        assert not np.any(got[k][:, :, P - 1:])
+        np.testing.assert_array_equal(
+            got[k], np.broadcast_to(got[k][:, :1], got[k].shape))
+
+
+@pytest.mark.parametrize("entry", ["apply_traj", "apply", "cache_init",
+                                   "tb_loss_grad"])
+def test_prompt_runs_once_per_traced_pass(env, policy, monkeypatch, entry):
+    """Each traced pass runs the prompt once at batch 1 (``shared_prefix``
+    counts it); no expanded attention call sees the prompt per row."""
+    params = seeded_params(CFG, 5)
+    conts = jax.random.randint(jax.random.PRNGKey(9), (B, T), 0,
+                               CFG.vocab_size)
+    obs = jnp.stack([jnp.where(jnp.arange(T) < t, conts, env.pad)
+                     for t in range(T + 1)])                 # (T+1, B, T)
+    calls = []
+    real = mla.mla_expanded
+
+    def recorded(p, x, positions, cfg, prefix=None):
+        calls.append((x.shape[:2], prefix is not None))
+        return real(p, x, positions, cfg, prefix)
+
+    monkeypatch.setattr(mla, "mla_expanded", recorded)
+    if entry == "tb_loss_grad":
+        batch = jax.jit(lambda p: forward_rollout(
+            jax.random.PRNGKey(7), env, env.init(jax.random.PRNGKey(0)),
+            policy, p, B))(params)
+        calls.clear()
+    run = {"apply_traj": lambda p: policy.apply_traj(p, obs),
+           "apply": lambda p: policy.apply(p, obs.reshape(-1, T)),
+           "cache_init": lambda p: policy.cache_init(p, B),
+           "tb_loss_grad": lambda p: jax.grad(make_loss_fn(
+               env, policy, GFNConfig(objective="tb")))(p, batch)}[entry]
+    before = mla.counters["shared_prefix"]
+    jax.jit(run).lower(params)
+    assert mla.counters["shared_prefix"] == before + 1
+    rows = {"apply_traj": B, "apply": (T + 1) * B, "tb_loss_grad": B}
+    want = {((1, P - 1), False)}
+    if entry in rows:
+        want.add(((rows[entry], T + 1), True))
+    assert set(calls) == want
+
+
 def test_reward_is_the_seeded_bigram_score(env):
     params = env.init(jax.random.PRNGKey(0))
     toks = jnp.array([[1, 2, 3, 4], [0, 0, 5, 63]], jnp.int32)
@@ -215,6 +314,36 @@ def test_rows_past_the_groups_stay_out_of_both_directions(monkeypatch):
                     jax.tree_util.tree_leaves(want)):
         assert np.all(np.isfinite(g))
         np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("tokens", [9, 255, 256])
+def test_grouped_matmul_rows_are_a_multiple_of_8(monkeypatch, tokens):
+    """XLA's TPU compiler takes its grouped-matmul kernel only for a row
+    count that is a multiple of 8 (the shared prompt's 255 tokens x 4
+    choices here are not; other counts get a dense expansion whose results
+    are wrong on the chip): the held experts pad their pairs to one, and
+    the padding changes no output."""
+    p = seeded_params(CFG, 7)["layers"]["layer_1"]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(10), (tokens, CFG.hidden_size))
+    idx, w = moe.sigmoid_topk_route(x, p["router"]["w"], p["router"]["bias"],
+                                    CFG.num_experts_per_tok,
+                                    CFG.routed_scaling_factor)
+    real, rows = jax.lax.ragged_dot, []
+
+    def recorded(lhs, rhs, sizes, *a, **kw):
+        rows.append(lhs.shape[0])
+        return real(lhs, rhs, sizes, *a, **kw)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", recorded)
+    got = moe.held_experts(p["experts"], x, idx, w, 0)
+    assert rows and all(r % 8 == 0 and r - 8 < tokens * idx.shape[1]
+                        for r in rows)
+    want = jnp.zeros_like(x)
+    for e in range(CFG.experts_held):
+        ex = jax.tree_util.tree_map(lambda a: a[:, e], p["experts"])
+        gate = jnp.sum(jnp.where(idx == e, w, 0.0), axis=-1)
+        want = want + gate[:, None] * mla_moe.silu_mlp(ex, x)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def test_lm_tb_trains_through_trainloop(env, policy):

@@ -12,8 +12,9 @@ its results are right (the interpret-mode oracle tests cover that).
 The ``lm_tb`` cell's shapes are compiled too: ``traj_logprob`` over a
 20480-id vocabulary slice (32 continuations of 64 tokens) and the held
 experts' grouped matmul (``models.moe.held_experts``: 8 of Moonlight's 64
-experts, 2048 -> 1408 -> 2048) on the teacher-forced pass's 32 x 320
-tokens, forward and backward.
+experts, 2048 -> 1408 -> 2048) on the per-row teacher-forced pass's 32 x
+320 tokens and on the shared prompt's, the rows' and a decode step's token
+counts, forward and backward.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test collection must be the
@@ -139,12 +140,10 @@ def test_traj_logprob_compiles_at_lm_vocab(spec, actions):
         spec((B, T, actions), jnp.bool_), spec((B, T), jnp.bool_))
 
 
-def test_held_experts_grouped_matmul_compiles(spec):
-    """Forward and backward of the held experts on the teacher-forced
-    pass's tokens: the grouped matmuls forward and their gradient products
-    backward lower to the TPU's ragged-dot kernels."""
+def _held_experts_grads(spec, tokens):
+    """The held experts' forward and backward over ``tokens`` tokens,
+    compiled for one described chip: its grouped-matmul calls and text."""
     from repro.models import moe
-    T = LM_ENVS * (LM_PROMPT + LM_STEPS)
 
     def loss(p, x, idx, w):
         return jnp.sum(moe.held_experts(p, x, idx, w, 0))
@@ -153,12 +152,33 @@ def test_held_experts_grouped_matmul_compiles(spec):
          "up": {"w": spec((D_MODEL, HELD, EXPERT_FF))},
          "down": {"w": spec((EXPERT_FF, HELD, D_MODEL))}}
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        p, spec((T, D_MODEL)), spec((T, TOP_K), jnp.int32),
-        spec((T, TOP_K))).compile().as_text()
+        p, spec((tokens, D_MODEL)), spec((tokens, TOP_K), jnp.int32),
+        spec((tokens, TOP_K))).compile().as_text()
     calls = [l for l in text.splitlines()
              if "custom-call(" in l and "%ragged-dot-" in l
              and "metadata" not in l.split("=")[0]]
+    return calls, text
+
+
+def test_held_experts_grouped_matmul_compiles(spec):
+    """Forward and backward of the held experts on the teacher-forced
+    pass's tokens: the grouped matmuls forward and their gradient products
+    backward lower to the TPU's ragged-dot kernels."""
+    calls, _ = _held_experts_grads(spec, LM_ENVS * (LM_PROMPT + LM_STEPS))
     assert len(calls) >= 6, calls
+
+
+@pytest.mark.parametrize("tokens", [LM_PROMPT - 1, LM_ENVS * (LM_STEPS + 1),
+                                    LM_ENVS],
+                         ids=["prompt", "rows", "decode"])
+def test_held_experts_take_the_kernel_at_every_row_count(spec, tokens):
+    """The shared prompt's pass (255 tokens x 6 choices, not a multiple of
+    8), the rows after it and a decode step: every grouped matmul takes
+    the ragged-dot kernel, and none the dense expansion (a convolution over
+    every held expert) that XLA gives other row counts."""
+    calls, text = _held_experts_grads(spec, tokens)
+    assert len(calls) >= 6, calls
+    assert " convolution(" not in text
 
 
 @pytest.mark.parametrize("batch", BATCHES)
