@@ -8,13 +8,22 @@ per-layer K/V cache (``core/rollout.py``'s cache-in-carry design).  That
 access pattern — q: (B, H, hd) single rows, k/v: (B, S, H, hd) cache slots, a
 per-batch valid-slot count — is the "decode" shape of LLM inference kernels:
 
-  grid = (B / 8, n_kv_blocks) with the kv axis innermost *sequential*; each
-  program owns 8 batch rows in the merged-head (…, H*hd) layout and streams
-  (8, block_k, D) K/V tiles HBM -> VMEM while the running-softmax state
-  (m, l, acc) lives in VMEM scratch across kv steps.  Slots at or beyond
-  ``kv_valid[b]`` (scalar-prefetched into SMEM) are masked before the
-  streaming max/sum update, so cache capacity can exceed the live prefix.
-  Rows with ``kv_valid == 0`` return a defined all-zero output.
+  grid = (B_pad / R, n_kv_blocks) with the kv axis innermost *sequential*;
+  each program owns a block of R batch rows in the merged-head (…, H*hd)
+  layout and streams (R, block_k, D) K/V tiles HBM -> VMEM while the
+  running-softmax state (m, l, acc), (R, D) each, lives in VMEM scratch
+  across kv steps.  The block is attended with vector operations over all
+  R rows at once: one ``(R * block_k, D) @ E`` matmul for the scores, then
+  the masked max, exp and sums over the slot axis.  R is the batch padded
+  to the 8-row f32 sublane tile, unless one (R, block_k, D) K or V block
+  would pass ``_KV_BLOCK_BYTES`` of VMEM; the batch is then split into the
+  fewest equal 8-aligned blocks under that cap (``_row_block``: four
+  64-row blocks for bitseq's 16-slot cache at 256 envs, one 16-row block
+  at 16 envs, 16-row blocks for AMP's 61-slot cache).  Slots at or beyond
+  ``kv_valid[b]`` (an (R, 1, 1) VMEM block per program) are masked before
+  the streaming max/sum update, so cache capacity can exceed the live
+  prefix.  Rows with ``kv_valid == 0`` return a defined all-zero output.
+  ``counters`` records the R of each traced call.
 
 ``decode_step_pallas`` — the fused decode STEP.  One program per 8
 environments executes the *entire* cached-rollout inner loop that
@@ -49,6 +58,7 @@ elsewhere (``interpret=None`` picks by platform).
 from __future__ import annotations
 
 import functools
+from collections import Counter
 from typing import Optional
 
 import jax
@@ -60,7 +70,13 @@ from . import resolve_interpret, round_up
 
 NEG_INF = -1e30
 ROWS = 8                       # batch rows per program: one f32 sublane tile
+_KV_BLOCK_BYTES = 512 << 10    # VMEM cap on one decode_attention K or V block
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+#: Rows per program of each traced ``decode_attention_pallas`` call
+#: (``rows_<R>``), counted at trace time, as ``core.objectives.counters``.
+counters: Counter = Counter()
 
 
 def _dot(a, b):
@@ -86,7 +102,7 @@ def _pad_axis(x, axis: int, size: int):
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, e_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, block_k: int, sm_scale: float, n_kv: int):
-    ib, ik = pl.program_id(0), pl.program_id(1)
+    ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
@@ -94,27 +110,27 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, e_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    e = e_ref[...]
-    pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
-    for r in range(ROWS):
-        live = pos < len_ref[ib * ROWS + r]                  # (block_k, 1)
-        q = q_ref[r:r + 1, :].astype(jnp.float32)            # (1, D)
-        k = k_ref[r].astype(jnp.float32)                     # (block_k, D)
-        v = v_ref[r].astype(jnp.float32)
-        s = jnp.where(live, _dot(k * q, e) * sm_scale, NEG_INF)
-        m_prev = m_scr[r:r + 1, :]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        # re-mask after the exp: a block with no live slot has
-        # m_new == NEG_INF and exp(s - m_new) == 1 on its masked slots;
-        # zeroing p keeps (l, acc) an empty sum, so rows with
-        # kv_valid == 0 finalize to a defined zero output
-        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[r:r + 1, :] = corr * l_scr[r:r + 1, :] + jnp.sum(
-            p, axis=0, keepdims=True)
-        acc_scr[r:r + 1, :] = corr * acc_scr[r:r + 1, :] + jnp.sum(
-            p * v, axis=0, keepdims=True)
-        m_scr[r:r + 1, :] = m_new
+    rows, dim = q_ref.shape
+    q = q_ref[...].astype(jnp.float32)                       # (R, D)
+    k = k_ref[...].astype(jnp.float32)                       # (R, block_k, D)
+    v = v_ref[...].astype(jnp.float32)
+    pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32,
+                                                  (1, block_k, 1), 1)
+    live = pos < len_ref[...]                                # (R, block_k, 1)
+    kq = (k * q[:, None, :]).reshape(rows * block_k, dim)
+    s = _dot(kq, e_ref[...]).reshape(rows, block_k, dim) * sm_scale
+    s = jnp.where(live, s, NEG_INF)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    # re-mask after the exp: a block with no live slot has
+    # m_new == NEG_INF and exp(s - m_new) == 1 on its masked slots;
+    # zeroing p keeps (l, acc) an empty sum, so rows with
+    # kv_valid == 0 finalize to a defined zero output
+    p = jnp.where(live, jnp.exp(s - m_new[:, None, :]), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1)
+    acc_scr[...] = corr * acc_scr[...] + jnp.sum(p * v, axis=1)
+    m_scr[...] = m_new
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
@@ -122,15 +138,34 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, e_ref, o_ref, m_scr, l_scr,
                       ).astype(o_ref.dtype)
 
 
+def _row_block(batch: int, block_k: int, dim: int) -> int:
+    """Batch rows per ``decode_attention`` program.
+
+    The whole batch, padded to the 8-row f32 sublane tile, unless one
+    (R, block_k, D) K or V block (f32, lanes padded to 128) would pass
+    ``_KV_BLOCK_BYTES``; the batch is then split into the fewest blocks
+    under that cap, of equal 8-row-aligned size.  The cap is small enough
+    that the next block's K/V copy overlaps this block's compute: on a TPU
+    v5e, four 64-row blocks over a 256 x 16-slot cache run faster than one
+    256-row block, and faster than 8-, 32- or 128-row blocks.
+    """
+    rows = round_up(batch, ROWS)
+    per_row = block_k * round_up(dim, 128) * 4
+    cap = max(ROWS, _KV_BLOCK_BYTES // per_row // ROWS * ROWS)
+    n_blocks = -(-rows // cap)
+    return round_up(-(-rows // n_blocks), ROWS)
+
+
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             kv_valid: jax.Array, *, block_k: int = 128,
                             interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, hd); k/v: (B, S, H, hd); kv_valid: (B,) valid slot counts.
 
-    Returns (B, H, hd).  The batch is padded to a multiple of 8 rows and the
-    cache axis to a ``block_k`` multiple internally; padded slots are masked
-    by the valid-count check.  Rows with ``kv_valid[b] == 0`` get an
-    all-zero output row (defined, not NaN/garbage).
+    Returns (B, H, hd).  The batch is padded to a multiple of the row block
+    (``_row_block``) and the cache axis to a ``block_k`` multiple
+    internally; padded slots are masked by the valid-count check.  Rows
+    with ``kv_valid[b] == 0`` get an all-zero output row (defined, not
+    NaN/garbage).
     """
     B, S, H, hd = k.shape
     D = H * hd
@@ -138,32 +173,33 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     # tile* — min(block_k, S) alone would yield unaligned blocks for
     # S % 8 != 0 and an oversized block (block_k > S) for S < 8
     block_k = min(block_k, round_up(max(S, 1), 8))
-    Sp, Bp = round_up(S, block_k), round_up(B, ROWS)
+    R = _row_block(B, block_k, D)
+    counters[f"rows_{R}"] += 1
+    Sp, Bp = round_up(S, block_k), round_up(B, R)
     qm = _pad_axis(q.reshape(B, D), 0, Bp)
     km = _pad_axis(_pad_axis(k.reshape(B, S, D), 1, Sp), 0, Bp)
     vm = _pad_axis(_pad_axis(v.reshape(B, S, D), 1, Sp), 0, Bp)
-    lens = _pad_axis(kv_valid.astype(jnp.int32), 0, Bp)
+    lens = _pad_axis(kv_valid.astype(jnp.int32).reshape(B, 1, 1), 0, Bp)
     n_kv = Sp // block_k
 
     kernel = functools.partial(_decode_kernel, block_k=block_k,
                                sm_scale=1.0 / (hd ** 0.5), n_kv=n_kv)
     out = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(Bp // ROWS, n_kv),
-            in_specs=[
-                pl.BlockSpec((ROWS, D), lambda b, ik, n: (b, 0)),
-                pl.BlockSpec((ROWS, block_k, D), lambda b, ik, n: (b, ik, 0)),
-                pl.BlockSpec((ROWS, block_k, D), lambda b, ik, n: (b, ik, 0)),
-                pl.BlockSpec((D, D), lambda b, ik, n: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((ROWS, D), lambda b, ik, n: (b, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((ROWS, D), jnp.float32),    # running max m
-                pltpu.VMEM((ROWS, D), jnp.float32),    # running denom l
-                pltpu.VMEM((ROWS, D), jnp.float32),    # output accumulator
-            ]),
+        grid=(Bp // R, n_kv),
+        in_specs=[
+            pl.BlockSpec((R, 1, 1), lambda b, ik: (b, 0, 0)),
+            pl.BlockSpec((R, D), lambda b, ik: (b, 0)),
+            pl.BlockSpec((R, block_k, D), lambda b, ik: (b, ik, 0)),
+            pl.BlockSpec((R, block_k, D), lambda b, ik: (b, ik, 0)),
+            pl.BlockSpec((D, D), lambda b, ik: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((R, D), lambda b, ik: (b, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((R, D), jnp.float32),    # running max m
+            pltpu.VMEM((R, D), jnp.float32),    # running denom l
+            pltpu.VMEM((R, D), jnp.float32),    # output accumulator
+        ],
         out_shape=jax.ShapeDtypeStruct((Bp, D), q.dtype),
         interpret=resolve_interpret(interpret),
     )(lens, qm, km, vm, _head_indicator(D, H))

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis or deterministic fallback
 
+from repro.kernels.decode_attention import counters as da_counters
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rwkv6_scan import rwkv6_scan_pallas
@@ -202,6 +203,9 @@ DECODE_CASES = [
     (2, 61, 8, 8, 16),      # AMP max_len=60 + BOS, tiled kv axis
     (3, 9, 4, 16, 8),       # TFBind8 + BOS, ragged block
     (1, 130, 2, 64, 128),   # kv axis > one block
+    (256, 16, 8, 8, 128),   # bitseq training batch: four 64-row blocks
+    (300, 16, 8, 8, 128),   # five 64-row blocks, the last one ragged
+    (64, 130, 2, 64, 128),  # long, wide cache: the VMEM cap gives 8 rows
 ]
 
 
@@ -243,6 +247,41 @@ def test_decode_attention_single_valid_slot_returns_that_value():
     out = decode_attention_pallas(q, k, v, jnp.array([1, 1]))
     np.testing.assert_allclose(np.asarray(out), np.asarray(v[:, 0]),
                                atol=1e-6)
+
+
+def test_decode_attention_empty_rows_in_a_wide_block_return_zeros():
+    """Rows with ``kv_valid == 0`` among live rows of one 64-row block."""
+    B, S, H, D = 64, 16, 8, 8
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, H, D))
+    k = jax.random.normal(ks[1], (B, S, H, D))
+    v = jax.random.normal(ks[2], (B, S, H, D))
+    kv_valid = jax.random.randint(ks[3], (B,), 1, S + 1)
+    empty = jnp.array([0, 5, 31, 32, 63])
+    kv_valid = kv_valid.at[empty].set(0)
+    out = decode_attention_pallas(q, k, v, kv_valid)
+    ref = ref_decode_attention(q, k, v, kv_valid)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    assert np.all(np.asarray(out)[np.asarray(empty)] == 0.0)
+
+
+@pytest.mark.parametrize("shape,rows", [
+    ((16, 16, 8, 8), 16),      # bitseq at the recipe's 16 envs: one block
+    ((256, 16, 8, 8), 64),     # bitseq at 256 envs: capped by the VMEM
+    ((256, 61, 8, 8), 16),     # AMP's 61-slot cache (block_k 64)
+    ((64, 130, 2, 64), 8),     # long, wide cache
+], ids=str)
+def test_decode_attention_row_block_counter(shape, rows):
+    """Each traced call counts the rows per program it chose."""
+    B, S, H, D = shape
+    before = da_counters[f"rows_{rows}"]
+    jax.eval_shape(lambda q, k, v, n: decode_attention_pallas(q, k, v, n),
+                   jax.ShapeDtypeStruct((B, H, D), jnp.float32),
+                   jax.ShapeDtypeStruct((B, S, H, D), jnp.float32),
+                   jax.ShapeDtypeStruct((B, S, H, D), jnp.float32),
+                   jax.ShapeDtypeStruct((B,), jnp.int32))
+    assert da_counters[f"rows_{rows}"] == before + 1
 
 
 def test_decode_attention_matches_cached_encoder_path():

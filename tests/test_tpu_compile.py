@@ -6,7 +6,9 @@ TPU is compiled here with ``interpret=False`` for one chip of a described
 ``v5e:2x2`` topology, at the shapes ``chip_smoke.py`` drives: the
 ``bitseq_tb`` recipe (n=120, k=8: 15 words, 3840 forward actions, a 3-layer
 width-64 8-head decode transformer) at the paper's 16 envs and at 256, and
-the ``hypergrid_subtb`` paper grid (20^4: 77-step trajectories).  Nothing
+the ``hypergrid_subtb`` paper grid (20^4: 77-step trajectories);
+``decode_attention`` also at ``amp_tb``'s 61-slot cache, where the VMEM cap
+on a K/V block gives smaller row blocks.  Nothing
 runs; a compile that passes says the compiler accepts the kernel, not that
 its results are right (the interpret-mode oracle tests cover that).
 The ``lm_tb`` cell's shapes are compiled too: ``traj_logprob`` over a
@@ -35,6 +37,7 @@ from repro.kernels.traj_logprob import traj_logprob_pallas
 LAYERS, DIM, HEADS, FF = 3, 64, 8, 256
 WORDS, ACTIONS = 15, 15 * 256          # L = n / k positions, L * 2^k actions
 CAPACITY = WORDS + 1                   # cache slots: BOS + one per word
+AMP_CAPACITY = 60 + 1                  # amp_tb: max_len 60 + BOS, block_k 64
 SUBTB_STATES = 4 * 19 + 2              # T + 1 for dim=4, side=20
 BATCHES = (16, 256)                    # paper num_envs, and a chip-filling one
 # lm_tb (recipes/lm.py): 32 continuations of 64 tokens after a 256-token
@@ -109,14 +112,17 @@ def test_decode_step_compiles(spec, batch):
                    spec((A,)), spec((B,)))
 
 
-@pytest.mark.parametrize("batch", BATCHES)
-def test_decode_attention_compiles(spec, batch):
+@pytest.mark.parametrize("batch,capacity", [
+    *(pytest.param(b, CAPACITY, id=str(b)) for b in BATCHES),
+    pytest.param(256, AMP_CAPACITY, id="256-amp"),
+])
+def test_decode_attention_compiles(spec, batch, capacity):
     hd = DIM // HEADS
     _assert_kernel(
         lambda q, k, v, n: decode_attention_pallas(q, k, v, n,
                                                    interpret=False),
-        spec((batch, HEADS, hd)), spec((batch, CAPACITY, HEADS, hd)),
-        spec((batch, CAPACITY, HEADS, hd)), spec((batch,), jnp.int32))
+        spec((batch, HEADS, hd)), spec((batch, capacity, HEADS, hd)),
+        spec((batch, capacity, HEADS, hd)), spec((batch,), jnp.int32))
 
 
 @pytest.mark.parametrize("batch", BATCHES)
